@@ -2,8 +2,9 @@
 
 One module per assigned architecture (exact public-literature configs), plus
 the paper's own eval model (llama2-13b).  Smoke configs are reduced same-
-family variants for CPU tests; full configs are exercised only via the
-dry-run (ShapeDtypeStruct, no allocation).
+family variants for CPU tests.  Full configs are compiled as shapes by the
+dry-run and by ``tests/test_chip_compile.py``; qwen3-4b also serves at full
+width on a TPU (``chip_smoke.py``, ``serve(..., smoke=False)``).
 """
 
 from __future__ import annotations

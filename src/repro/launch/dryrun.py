@@ -1,9 +1,19 @@
+"""Multi-pod dry run: lower and compile every (arch x shape x mesh) cell on
+512 placeholder CPU devices and record memory, cost and collectives.
+
+    PYTHONPATH=src python -m repro.launch.dryrun --arch qwen3-4b --shape decode_32k
+    PYTHONPATH=src python -m repro.launch.dryrun --all
+
+A CPU-only planner: it never takes an accelerator from another process.
+"""
+
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ MUST precede any jax import: jax locks the device count at first init.
-#   512 placeholder host devices back both the 16x16 single-pod mesh and the
-#   2x16x16 multi-pod mesh.  This is dry-run-only (DESIGN.md; smoke tests and
-#   benches see the real single CPU device).
+os.environ["JAX_PLATFORMS"] = "cpu"
+# ^ MUST precede any jax import: jax locks the device count and the backend
+#   at first init.  512 placeholder host devices back both the 16x16
+#   single-pod mesh and the 2x16x16 multi-pod mesh.  The environment is
+#   inherited by the --all children, so they stay on the CPU too.
 
 import argparse          # noqa: E402
 import json              # noqa: E402

@@ -1,28 +1,22 @@
-"""Serving launcher: run the continuous-batching engine with a pluggable
-admission scheduler over the paper's mixed workload.
+"""Serving launcher: a command line over :func:`repro.serving.api.serve`,
+serving the paper's mixed workload with a pluggable admission scheduler.
 
     PYTHONPATH=src python -m repro.launch.serve --scheduler ewsjf --requests 32
+    PYTHONPATH=src python -m repro.launch.serve --full   # published widths, bf16
+
+``--full`` needs an accelerator that holds the model (qwen3-4b: one 16 GB
+TPU v5e chip); without it the architecture's CPU smoke preset is served.
 """
 
 from __future__ import annotations
 
 import argparse
 
-import jax
 import numpy as np
 
-from ..configs import get_smoke_config
-from ..core import (EWSJFConfig, EWSJFScheduler, FCFSScheduler,
-                    Request, SJFScheduler)
-from ..models import init_params
-from ..serving import EngineConfig, ServingEngine
-
-
-def make_scheduler(name: str):
-    if name == "ewsjf":
-        return EWSJFScheduler(EWSJFConfig(min_history=8, reopt_interval=1.0,
-                                          trial_interval=5.0))
-    return {"fcfs": FCFSScheduler, "sjf": SJFScheduler}[name]()
+from ..core import Request
+from ..serving.api import SCHEDULERS, serve
+from .compile_cache import enable_compile_cache
 
 
 def mixed_requests(n: int, seed: int = 0) -> list[Request]:
@@ -39,23 +33,17 @@ def mixed_requests(n: int, seed: int = 0) -> list[Request]:
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-4b")
-    ap.add_argument("--scheduler", default="ewsjf",
-                    choices=["ewsjf", "fcfs", "sjf"])
+    ap.add_argument("--scheduler", default="ewsjf", choices=sorted(SCHEDULERS))
     ap.add_argument("--requests", type=int, default=32)
-    ap.add_argument("--max-slots", type=int, default=4)
+    ap.add_argument("--full", action="store_true",
+                    help="serve the published widths in bfloat16")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
-    cfg = get_smoke_config(args.arch)
-    params = init_params(jax.random.PRNGKey(0), cfg)
-    sched = make_scheduler(args.scheduler)
-    eng = ServingEngine(cfg, params, sched,
-                        EngineConfig(max_slots=args.max_slots, s_max=256,
-                                     kv_pool_tokens=2048,
-                                     buckets=(32, 64, 128, 256)))
-    reqs = mixed_requests(args.requests, args.seed)
-    fin = eng.run(reqs)
-    st = eng.stats()
+    enable_compile_cache()
+    res = serve(args.arch, mixed_requests(args.requests, args.seed),
+                scheduler=args.scheduler, smoke=not args.full, seed=args.seed)
+    fin, st = res["finished"], res["stats"]
     ttft = np.asarray([r.ttft for r in fin if r.ttft is not None])
     short = np.asarray([r.ttft for r in fin
                         if r.ttft is not None and r.prompt_len <= 32])

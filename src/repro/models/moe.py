@@ -29,11 +29,6 @@ import jax.numpy as jnp
 from ..configs.base import ModelConfig
 from .common import dense_init
 
-try:                                    # jax>=0.6 moved shard_map
-    shard_map = jax.shard_map
-except AttributeError:                  # older jax: experimental home
-    from jax.experimental.shard_map import shard_map
-
 
 def init_moe(key, cfg: ModelConfig, dtype) -> dict:
     d, E, ff = cfg.d_model, cfg.n_experts, cfg.moe_d_ff
@@ -215,7 +210,7 @@ def moe_ep_a2a(params, x, cfg: ModelConfig, mesh, *, batch_axes=("data",),
         y_out = y_tok.reshape(N, k, d).sum(1).astype(x_loc.dtype)
         return y_out.reshape(B_loc, S, d), probs, idx
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(x_spec, P(), ew_spec, ew_spec, ew_spec),
         out_specs=(x_spec, P(batch_axes, None), P(batch_axes, None)),

@@ -125,16 +125,22 @@ class ServingEngine:
     ``core.scheduler`` policy, and an ``EngineConfig``; drive with ``run``
     (batch) or ``add_request`` + the internal ticks (streaming).  Optional
     collaborators mirror the cluster planes: ``admission`` (SLO ingress),
-    ``policy_store`` (strategic sync), ``obs`` (observability)."""
+    ``policy_store`` (strategic sync), ``obs`` (observability).
+
+    ``device`` commits the engine's params, caches, PRNG key and per-step
+    inputs to one device, so several engines in one process each drive
+    their own chip; ``None`` leaves placement to JAX's default device."""
 
     def __init__(self, cfg: ModelConfig, params, scheduler: BaseScheduler,
                  ecfg: EngineConfig | None = None,
                  policy: DtypePolicy | None = None,
                  admission=None, policy_store=None,
                  replica_key: Optional[int] = None,
-                 obs=None, cost_model: Optional[CostModel] = None):
+                 obs=None, cost_model: Optional[CostModel] = None,
+                 device: Optional[jax.Device] = None):
         self.cfg = cfg
-        self.params = params
+        self.device = device
+        self.params = jax.device_put(params, device)
         self.sched = scheduler
         self.e = ecfg or EngineConfig()
         self.policy = policy or DtypePolicy(jnp.float32, jnp.float32,
@@ -169,8 +175,12 @@ class ServingEngine:
         self.chunk_tokens = 0
         self.prefix_saved_tokens = 0
         self.interleaved_ticks = 0   # decode ticks run while a prefill was up
-        self.caches = init_decode_caches(cfg, self.e.max_slots, self.e.s_max,
-                                         dtype=self.policy.compute)
+        with jax.default_device(device):
+            caches = init_decode_caches(cfg, self.e.max_slots, self.e.s_max,
+                                        dtype=self.policy.compute)
+            key = jax.random.PRNGKey(self.e.seed)
+        self.caches = jax.device_put(caches, device)
+        self._key = jax.device_put(key, device)
         self.slot_pos = np.zeros(self.e.max_slots, dtype=np.int32)
         self.slot_state: dict[int, _SlotState] = {}
         self.last_tokens = np.zeros((self.e.max_slots, 1), dtype=np.int32)
@@ -226,12 +236,16 @@ class ServingEngine:
         self.prefill_batches = 0
         self.padded_tokens = 0
         self.real_tokens = 0
-        self._key = jax.random.PRNGKey(self.e.seed)
         self._decode_jit = jax.jit(self._decode_fn)
         self._prefill_jits: dict = {}
         self._t0 = time.monotonic()
 
     # ---- compiled steps --------------------------------------------------
+
+    def _put(self, x):
+        """Host input -> device array on the engine's device.  Always a
+        copy: the engine rewrites its host buffers in place after dispatch."""
+        return jax.device_put(x, self.device, may_alias=False)
 
     def _decode_fn(self, params, tokens, caches, pos):
         logits, new_caches = decode_step(params, tokens, caches, pos,
@@ -331,11 +345,11 @@ class ServingEngine:
 
         def flat_at(dst, src):
             return dst.at[slot, lo:lo + src.shape[0]].set(
-                jnp.asarray(src).astype(dst.dtype))
+                self._put(src).astype(dst.dtype))
 
         def stacked_at(dst, src):
             return dst.at[:, slot, lo:lo + src.shape[1]].set(
-                jnp.asarray(src).astype(dst.dtype))
+                self._put(src).astype(dst.dtype))
 
         new = dict(self.caches)
         new["head"] = [jax.tree.map(flat_at, d, s)
@@ -643,7 +657,7 @@ class ServingEngine:
         fresh_jit = (bucket, n) not in self._prefill_jits
         fn = self._get_prefill_jit(bucket, n)
         t_pf0 = self.now()
-        logits, caches = fn(self.params, jnp.asarray(tokens), jnp.asarray(lens))
+        logits, caches = fn(self.params, self._put(tokens), self._put(lens))
         caches = pad_prefill_caches(caches, self.cfg, self.e.s_max)
         self._key, sk = jax.random.split(self._key)
         first = np.asarray(sample_tokens(logits, sk,
@@ -855,8 +869,9 @@ class ServingEngine:
             fresh_jit = width not in self._chunk_jits
             fn = self._get_chunk_jit(width)
             t0 = self.now()
-            logits, new_sl = fn(self.params, jnp.asarray(toks),
-                                self._slice_slot(slot), jnp.int32(st.pos))
+            logits, new_sl = fn(self.params, self._put(toks),
+                                self._slice_slot(slot),
+                                self._put(np.int32(st.pos)))
             self._write_slot(slot, new_sl, 0)
             st.pos += width
             t1 = self.now()
@@ -983,8 +998,8 @@ class ServingEngine:
                     if len(self.slot_state) > 1:
                         self._preempt_slot(slot)
                     # else: single sequence — let it run (pool undersized)
-            toks = jnp.asarray(self.last_tokens)
-            pos = jnp.asarray(self.slot_pos)
+            toks = self._put(self.last_tokens)
+            pos = self._put(self.slot_pos)
             logits, self.caches = self._decode_jit(self.params, toks,
                                                    self.caches, pos)
             self._key, sk = jax.random.split(self._key)

@@ -58,6 +58,37 @@ def test_engine_ewsjf_reduces_padding(model):
     assert stats["ewsjf"]["padding_waste"] < stats["fcfs"]["padding_waste"] - 0.1
 
 
+@pytest.mark.parametrize("chunked", [False, True])
+def test_engine_device_commits_state_and_keeps_tokens(model, chunked):
+    """An engine given a device keeps params, caches and PRNG key committed
+    there through serving, and samples the tokens the default placement
+    samples."""
+    cfg, params = model
+    dev = jax.devices()[-1]
+    outs = {}
+    for device in (None, dev):
+        reqs = mixed_requests(8, seed=3)
+        for i, r in enumerate(reqs):
+            r.prompt_tokens = ((np.arange(r.prompt_len) * 5 + i)
+                               % cfg.vocab_size).astype(np.int32)
+        eng = ServingEngine(cfg, params, FCFSScheduler(),
+                            EngineConfig(max_slots=4, s_max=256,
+                                         kv_pool_tokens=4096,
+                                         buckets=(32, 64, 128, 256),
+                                         chunk_prefill_tokens=(
+                                             64 if chunked else None),
+                                         enable_prefix_cache=chunked),
+                            device=device)
+        built = jax.tree.leaves((eng.params, eng.caches, eng._key))
+        eng.run(reqs, max_steps=2000)
+        outs[device] = [eng.output_tokens[r.request_id] for r in reqs]
+        served = jax.tree.leaves((eng.params, eng.caches, eng._key))
+        if device is not None:
+            for x in built + served:
+                assert x.committed and x.devices() == {dev}
+    assert outs[None] == outs[dev]
+
+
 def test_engine_outputs_independent_of_scheduler(model):
     """Greedy decoding: each request's tokens must not depend on the
     admission order (isolation of slots + per-row positions)."""

@@ -84,7 +84,8 @@ class TestDryRunSmoke:
             os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
             import jax
             from repro.launch.cells import build_cell
-            mesh = jax.make_mesh((2, 4), ("data", "model"))
+            from repro.launch.mesh import make_host_mesh
+            mesh = make_host_mesh(model_parallel=4)     # (data 2, model 4)
             with mesh:
                 for arch, shape in [("qwen3-4b", "train_4k"),
                                     ("qwen3-4b", "decode_32k"),
