@@ -1,0 +1,11 @@
+"""Mean time to first token of every request sent in the window, from due
+time; one still waiting at the close counts at its wait, so a long request
+left to starve raises it."""
+
+from statistics import fmean
+
+from perfbench.stats import ttfts
+
+
+def read(run):
+    return fmean(ttfts(run.window.sent, run.window.close))
