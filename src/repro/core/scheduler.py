@@ -21,6 +21,7 @@ from .batch_builder import BatchBudget, BatchBuilder
 from .cost_model import CostModel, make_cost_fn
 from .meta_optimizer import BayesianMetaOptimizer
 from .monitor import Monitor, RewardWeights, reward, reward_terms
+from ..obs.trace import span
 from .partition import PartitionConfig, refine_and_prune
 from .queues import QueueManager, SchedulerQueue
 from .scoring import QueueProfile, compute_score, weights_for_queue
@@ -461,15 +462,16 @@ class EWSJFScheduler(BaseScheduler):
         return self._trial_meta or self.manager.meta
 
     def _repartition(self, lengths: np.ndarray) -> None:
-        meta = self._current_meta()
-        if self.partitioner is not None:
-            bounds = self.partitioner(lengths)
-        else:
-            pcfg = PartitionConfig(alpha_split=meta.alpha_split,
-                                   max_queues=meta.max_queues)
-            bounds = refine_and_prune(lengths, pcfg)
-        self.manager.apply_policy(bounds, meta)
-        self._mark_snapshot_dirty()
+        with span("sched.repartition", history=len(lengths)):
+            meta = self._current_meta()
+            if self.partitioner is not None:
+                bounds = self.partitioner(lengths)
+            else:
+                pcfg = PartitionConfig(alpha_split=meta.alpha_split,
+                                       max_queues=meta.max_queues)
+                bounds = refine_and_prune(lengths, pcfg)
+            self.manager.apply_policy(bounds, meta)
+            self._mark_snapshot_dirty()
 
     def online_adjust(self, now: float) -> None:
         """Online (real-time) mode (§3.1): lightweight boundary nudges from
@@ -608,18 +610,19 @@ class EWSJFScheduler(BaseScheduler):
         if now - self._trial_start < self.cfg.trial_interval:
             return
         # Close the trial: compute reward over the trial window.
-        elapsed = max(now - self._trial_start, 1e-9)
-        stats = self.monitor.window_stats(elapsed)
-        qlens = [np.asarray([r.work_len for r in q.requests],
-                            dtype=np.float64)
-                 for q in self.manager.queues]
-        terms = reward_terms(qlens, stats, len(self.manager.queues))
-        tokens = self.monitor.total_tokens_out - self._trial_token_mark
-        thr_bonus = tokens / elapsed / 1000.0
-        r = reward(terms, self.cfg.reward_weights, throughput_bonus=thr_bonus)
-        self.meta_opt.observe(self._trial_meta, r)
-        nxt = self.meta_opt.suggest()
-        self._trial_meta = nxt
+        with span("sched.meta_trial"):
+            elapsed = max(now - self._trial_start, 1e-9)
+            stats = self.monitor.window_stats(elapsed)
+            qlens = [np.asarray([r.work_len for r in q.requests],
+                                dtype=np.float64)
+                     for q in self.manager.queues]
+            terms = reward_terms(qlens, stats, len(self.manager.queues))
+            tokens = self.monitor.total_tokens_out - self._trial_token_mark
+            thr_bonus = tokens / elapsed / 1000.0
+            r = reward(terms, self.cfg.reward_weights,
+                       throughput_bonus=thr_bonus)
+            self.meta_opt.observe(self._trial_meta, r)
+            self._trial_meta = self.meta_opt.suggest()
         self._trial_start = now
         self._trial_finish_mark = self.monitor.total_finished
         self._trial_token_mark = self.monitor.total_tokens_out

@@ -15,7 +15,9 @@ edges (``lo · growth^i``), so
 
 * a quantile estimate is always within **one bucket bound** of the exact
   sample quantile (the estimate is the upper edge of the bucket holding
-  the exact value, tested in tests/test_obs.py);
+  the exact value, tested in tests/test_obs.py) for any sample above
+  ``lo / growth``: with the default layout, any positive time the host
+  clock can read;
 * histograms **merge associatively** (bucket counts add), so per-shard /
   per-replica histograms can be pooled into fleet views without ever
   shipping raw samples — the property the 10k-replica control-plane
@@ -51,11 +53,15 @@ class HistogramSpec:
     """Geometric bucket layout: upper edges ``lo * growth**i``.
 
     ``growth`` is the percentile error bound: an estimate never exceeds
-    the exact quantile by more than one bucket (factor ``growth``)."""
+    the exact quantile by more than one bucket (factor ``growth``), for
+    samples above ``lo / growth``.  The default's first edge, 1e-4 / 2**17
+    (7.6e-10 s), lies below the host clock's 1 ns resolution, so the bound
+    holds for every positive duration and count; its 61 buckets end at
+    1e-4 * 2**43 (8.8e8), as 44 buckets from 1e-4 did, on the same edges."""
 
-    lo: float = 1e-4          # first upper edge (underflow bucket [0, lo])
-    growth: float = 2.0       # geometric bucket ratio
-    n_buckets: int = 44       # covers lo .. lo*growth^(n-1); then overflow
+    lo: float = 1e-4 / 2 ** 17   # first upper edge (underflow bucket [0, lo])
+    growth: float = 2.0          # geometric bucket ratio
+    n_buckets: int = 61          # covers lo .. lo*growth^(n-1); then overflow
 
     def edges(self) -> list[float]:
         """All finite upper edges, ascending."""

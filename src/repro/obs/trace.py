@@ -29,6 +29,15 @@ hardware flight recorder survives the crash it records.
 map to processes (pid), requests to threads (tid) so Perfetto groups a
 request's lifecycle on one track; spans use phase ``X``, instants phase
 ``i``.  ``tools/trace_summary.py`` consumes the same JSON offline.
+
+**Profiler spans**: ``span`` makes the ``jax.profiler.TraceAnnotation``
+with which the serving engine and the EWSJF scheduler mark their host work
+(``engine.tick``, ``sched.tick``, ``engine.decode_step``, ...).  They land
+in a ``jax.profiler`` trace on the device's clock, beside the programs
+they launch, and touch neither the ring nor any state.
+``program_builds`` counts the programs JAX builds, for
+``engine_compile_cache_total``.  JAX is imported on first use, so the
+package stays importable without it.
 """
 
 from __future__ import annotations
@@ -56,7 +65,50 @@ LIFECYCLE_KINDS = (
 SPAN_STAGES = {
     "prefill": "prefill", "chunk": "prefill", "recompute": "prefill",
     "attach": "attach", "decode": "decode",
+    # Profiler spans (``span`` below) of the same work.
+    "engine.prefill": "prefill", "engine.chunk": "prefill",
+    "engine.decode_step": "decode",
 }
+
+BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+
+
+def span(name: str, **args):
+    """A ``jax.profiler.TraceAnnotation`` over the host work it encloses,
+    on the profiler's own clock.  ``args`` are scalars, shown beside the
+    span.  With no profiler session running it records nothing and costs
+    about a microsecond and a half; the session is the only switch."""
+    global _annotation
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation
+        _annotation = TraceAnnotation
+    return _annotation(name, **args)
+
+
+_annotation = None
+
+
+class _Builds:
+    count = 0
+    listening = False
+
+
+def _count_build(event: str, secs: float, **kw) -> None:
+    if event == BACKEND_COMPILE:
+        _Builds.count += 1
+
+
+def program_builds() -> int:
+    """Programs JAX has built in this process since the first call: each
+    backend compile, and each load from the persistent compilation cache
+    (JAX times both as one ``backend_compile`` event).  The first call
+    registers the process-wide ``jax.monitoring`` listener; read it before
+    and after a jitted call to learn whether the call built its program."""
+    if not _Builds.listening:
+        import jax.monitoring
+        jax.monitoring.register_event_duration_secs_listener(_count_build)
+        _Builds.listening = True
+    return _Builds.count
 
 
 @dataclass(slots=True)

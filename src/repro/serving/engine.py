@@ -61,6 +61,7 @@ if TYPE_CHECKING:   # runtime import is deferred: kvplane.radix imports
                                                    # module-level import here
                                                    # would close the cycle
 from ..models.common import DtypePolicy
+from ..obs.trace import program_builds, span
 from ..models.model import (_embed_inputs, _unembed, chunk_step, decode_step,
                             init_decode_caches, pad_prefill_caches)
 from ..models.common import rms_norm
@@ -232,7 +233,6 @@ class ServingEngine:
         self.finished: list[Request] = []
         self.tokens_out = 0              # every sampled token (heartbeats)
         self.preemptions = 0
-        self._decode_compiled = False    # first decode tick includes JIT
         self.prefill_batches = 0
         self.padded_tokens = 0
         self.real_tokens = 0
@@ -492,15 +492,17 @@ class ServingEngine:
         admits nothing new (its queue was drained back to the router)."""
         if not self.alive:
             return
-        now = self.now()
-        self._pump_retries(now)
-        if hasattr(self.sched, "maybe_reoptimize"):
-            self.sched.maybe_reoptimize(now)
-        self._maybe_sync_policy(now)
-        if not self.draining:
-            self._admit(now)
-        self._prefill_chunk_tick(now)
-        self._decode_tick()
+        with span("engine.tick", engine=self.e.engine_id,
+                  active=len(self.slot_state), waiting=self.sched.waiting()):
+            now = self.now()
+            self._pump_retries(now)
+            if hasattr(self.sched, "maybe_reoptimize"):
+                self.sched.maybe_reoptimize(now)
+            self._maybe_sync_policy(now)
+            if not self.draining:
+                self._admit(now)
+            self._prefill_chunk_tick(now)
+            self._decode_tick()
         if self.draining and not self.has_work():
             self.alive = False
 
@@ -626,7 +628,8 @@ class ServingEngine:
                              max_tokens=self.e.max_prefill_tokens,
                              kv_blocks_free=self.pool.free_blocks,
                              block_size=self.e.block_size)
-        plan = self.sched.tick(now, budget)
+        with span("sched.tick", waiting=self.sched.waiting(), free=free):
+            plan = self.sched.tick(now, budget)
         if not plan.requests:
             return
         if self._chunked:
@@ -641,81 +644,88 @@ class ServingEngine:
                       self.e.buckets[-1])
         if not self.e.pad_prompts:
             bucket = max_len
-        tokens = np.zeros((n, bucket), dtype=np.int32)
-        lens = np.zeros((n,), dtype=np.int32)
-        rng = np.random.default_rng(sum(r.request_id for r in reqs))
-        for i, r in enumerate(reqs):
-            if r.prompt_tokens is None:
-                r.prompt_tokens = rng.integers(
-                    0, self.cfg.vocab_size, size=(r.prompt_len,)
-                ).astype(np.int32)
-            tokens[i, : r.prompt_len] = r.prompt_tokens
-            lens[i] = r.prompt_len
-        self.prefill_batches += 1
-        self.padded_tokens += bucket * n
-        self.real_tokens += int(lens.sum())
-        fresh_jit = (bucket, n) not in self._prefill_jits
-        fn = self._get_prefill_jit(bucket, n)
-        t_pf0 = self.now()
-        logits, caches = fn(self.params, self._put(tokens), self._put(lens))
-        caches = pad_prefill_caches(caches, self.cfg, self.e.s_max)
-        self._key, sk = jax.random.split(self._key)
-        first = np.asarray(sample_tokens(logits, sk,
-                                         temperature=self.e.temperature))
-        t_first = self.now()
-        # observed prefill rate feeds the admission delay estimator; skip
-        # first-call-per-shape timings — they include JIT compilation and
-        # would poison the estimate into spurious shedding
-        if not fresh_jit:
-            rate = int(lens.sum()) / max(t_first - t_pf0, 1e-6)
-            self._prefill_tok_rate = (rate if self._prefill_tok_rate <= 0 else
-                                      0.7 * self._prefill_tok_rate + 0.3 * rate)
-        if self.obs is not None:
-            self.obs.event("prefill", t_pf0, dur=max(t_first - t_pf0, 0.0),
-                           replica_id=self.e.engine_id,
-                           data={"batch": n, "bucket": bucket,
-                                 "tokens": int(lens.sum())})
-            self.obs.inc("engine_compile_cache_total",
-                         {"kind": "prefill",
-                          "hit": "false" if fresh_jit else "true"})
-            # Calibration sample: batch prefill is prefill-shaped work.
-            # First-call-per-shape walls include XLA compilation and would
-            # poison the fit the same way they would the rate EWMA — skip.
-            if self.cost is not None and not fresh_jit:
-                self.obs.calibrate(
-                    "prefill_chunk",
-                    self.cost.prefill_step_time(int(lens.sum()),
-                                                float(lens.mean())),
-                    max(t_first - t_pf0, 1e-9))
-        for i, r in enumerate(reqs):
-            self.pool.allocate(r.request_id, r.prompt_len)
-            slot = self.slots.acquire(r.request_id)
-            assert slot is not None
-            self._write_slot(slot, caches, i)
-            r.state = RequestState.RUNNING_DECODE
-            r.first_token_time = t_first
-            self.dispatch_log.append((t_pf0, r.request_id))
-            self._slot_last_tok[slot] = t_first
+        with span("engine.prefill", rows=n, bucket=bucket,
+                  tokens=sum(r.prompt_len for r in reqs)):
+            tokens = np.zeros((n, bucket), dtype=np.int32)
+            lens = np.zeros((n,), dtype=np.int32)
+            rng = np.random.default_rng(sum(r.request_id for r in reqs))
+            for i, r in enumerate(reqs):
+                if r.prompt_tokens is None:
+                    r.prompt_tokens = rng.integers(
+                        0, self.cfg.vocab_size, size=(r.prompt_len,)
+                    ).astype(np.int32)
+                tokens[i, : r.prompt_len] = r.prompt_tokens
+                lens[i] = r.prompt_len
+            self.prefill_batches += 1
+            self.padded_tokens += bucket * n
+            self.real_tokens += int(lens.sum())
+            fn = self._get_prefill_jit(bucket, n)
+            t_pf0 = self.now()
+            builds = program_builds()
+            logits, caches = fn(self.params, self._put(tokens),
+                                self._put(lens))
+            built = program_builds() != builds
+            caches = pad_prefill_caches(caches, self.cfg, self.e.s_max)
+            self._key, sk = jax.random.split(self._key)
+            first = np.asarray(sample_tokens(logits, sk,
+                                             temperature=self.e.temperature))
+            t_first = self.now()
+            # observed prefill rate feeds the admission delay estimator; skip
+            # calls that built their program — they include JIT compilation
+            # and would poison the estimate into spurious shedding
+            if not built:
+                rate = int(lens.sum()) / max(t_first - t_pf0, 1e-6)
+                self._prefill_tok_rate = (
+                    rate if self._prefill_tok_rate <= 0 else
+                    0.7 * self._prefill_tok_rate + 0.3 * rate)
             if self.obs is not None:
-                wait = max(0.0, t_pf0 - r.arrival_time)
-                self.obs.event("dispatch", t_pf0, request_id=r.request_id,
+                self.obs.event("prefill", t_pf0,
+                               dur=max(t_first - t_pf0, 0.0),
                                replica_id=self.e.engine_id,
-                               data={"wait": round(wait, 6)})
-                self.obs.observe("sched_dispatch_wait_seconds", wait,
-                                 {"slo_class": self.obs.classify(r)})
-                self.obs.event("first_token", t_first,
-                               request_id=r.request_id,
-                               replica_id=self.e.engine_id)
-            r.generated = 1
-            self.tokens_out += 1
-            self.output_tokens[r.request_id] = [int(first[i, 0])]
-            self.slot_pos[slot] = r.prompt_len
-            self.last_tokens[slot, 0] = first[i, 0]
-            self.slot_state[slot] = _SlotState(
-                req=r, seq_id=r.request_id,
-                budget_left=r.max_new_tokens - 1)
-            if r.max_new_tokens <= 1:
-                self._finish_slot(slot)
+                               data={"batch": n, "bucket": bucket,
+                                     "tokens": int(lens.sum())})
+                self.obs.inc("engine_compile_cache_total",
+                             {"kind": "prefill",
+                              "hit": "false" if built else "true"})
+                # Calibration sample: batch prefill is prefill-shaped work.
+                # Walls of calls that built their program include XLA
+                # compilation and would poison the fit the same way they
+                # would the rate EWMA — skip.
+                if self.cost is not None and not built:
+                    self.obs.calibrate(
+                        "prefill_chunk",
+                        self.cost.prefill_step_time(int(lens.sum()),
+                                                    float(lens.mean())),
+                        max(t_first - t_pf0, 1e-9))
+            for i, r in enumerate(reqs):
+                self.pool.allocate(r.request_id, r.prompt_len)
+                slot = self.slots.acquire(r.request_id)
+                assert slot is not None
+                self._write_slot(slot, caches, i)
+                r.state = RequestState.RUNNING_DECODE
+                r.first_token_time = t_first
+                self.dispatch_log.append((t_pf0, r.request_id))
+                self._slot_last_tok[slot] = t_first
+                if self.obs is not None:
+                    wait = max(0.0, t_pf0 - r.arrival_time)
+                    self.obs.event("dispatch", t_pf0, request_id=r.request_id,
+                                   replica_id=self.e.engine_id,
+                                   data={"wait": round(wait, 6)})
+                    self.obs.observe("sched_dispatch_wait_seconds", wait,
+                                     {"slo_class": self.obs.classify(r)})
+                    self.obs.event("first_token", t_first,
+                                   request_id=r.request_id,
+                                   replica_id=self.e.engine_id)
+                r.generated = 1
+                self.tokens_out += 1
+                self.output_tokens[r.request_id] = [int(first[i, 0])]
+                self.slot_pos[slot] = r.prompt_len
+                self.last_tokens[slot, 0] = first[i, 0]
+                self.slot_state[slot] = _SlotState(
+                    req=r, seq_id=r.request_id,
+                    budget_left=r.max_new_tokens - 1)
+                if r.max_new_tokens <= 1:
+                    self._finish_slot(slot)
 
     def _write_slot(self, slot: int, prefill_caches, row: int) -> None:
         """Copy row ``row`` of a prefill cache pytree into the decode slot.
@@ -727,7 +737,8 @@ class ServingEngine:
         def stacked(dst, src):
             return dst.at[:, slot].set(src[:, row].astype(dst.dtype))
 
-        self._map_into_caches(prefill_caches, flat, stacked)
+        with span("engine.write_slot", slot=slot):
+            self._map_into_caches(prefill_caches, flat, stacked)
 
     # ---- chunked admission + prefill (convergence mode) -------------------
 
@@ -864,22 +875,24 @@ class ServingEngine:
             pos0 = st.pos
             width = min(int(r.prompt_len) - st.pos, left)
             left -= width
-            toks = np.asarray(r.prompt_tokens[st.pos:st.pos + width],
-                              dtype=np.int32)[None]
-            fresh_jit = width not in self._chunk_jits
-            fn = self._get_chunk_jit(width)
-            t0 = self.now()
-            logits, new_sl = fn(self.params, self._put(toks),
-                                self._slice_slot(slot),
-                                self._put(np.int32(st.pos)))
-            self._write_slot(slot, new_sl, 0)
-            st.pos += width
-            t1 = self.now()
+            with span("engine.chunk", slot=slot, width=width):
+                toks = np.asarray(r.prompt_tokens[st.pos:st.pos + width],
+                                  dtype=np.int32)[None]
+                fn = self._get_chunk_jit(width)
+                t0 = self.now()
+                builds = program_builds()
+                logits, new_sl = fn(self.params, self._put(toks),
+                                    self._slice_slot(slot),
+                                    self._put(np.int32(st.pos)))
+                built = program_builds() != builds
+                self._write_slot(slot, new_sl, 0)
+                st.pos += width
+                t1 = self.now()
             self.chunks_run += 1
             self.chunk_tokens += width
             self.real_tokens += width
             self.padded_tokens += width      # chunk path pads nothing
-            if not fresh_jit:
+            if not built:
                 rate = width / max(t1 - t0, 1e-6)
                 self._prefill_tok_rate = (
                     rate if self._prefill_tok_rate <= 0 else
@@ -899,12 +912,12 @@ class ServingEngine:
                 self.obs.observe("engine_chunk_width_tokens", float(width))
                 self.obs.inc("engine_compile_cache_total",
                              {"kind": "chunk",
-                              "hit": "false" if fresh_jit else "true"})
+                              "hit": "false" if built else "true"})
                 # Calibration sample: roofline prediction for prefilling a
                 # prompt to pos0+width with pos0 tokens already resident —
-                # exactly this chunk's suffix work.  Fresh-JIT walls
-                # include compilation and are skipped.
-                if self.cost is not None and not fresh_jit:
+                # exactly this chunk's suffix work.  Walls of calls that
+                # built their program include compilation and are skipped.
+                if self.cost is not None and not built:
                     self.obs.calibrate(
                         "prefill_chunk",
                         self.cost.prefill_cost(pos0 + width, cached=pos0),
@@ -985,9 +998,38 @@ class ServingEngine:
         # uses the composition the tick started with).
         batch0 = len(self.slot_state)
         kv0 = int(sum(int(self.slot_pos[s]) for s in self.slot_state))
+        built = False
         for _ in range(self.e.decode_steps_per_tick):
             if not self.slot_state:
                 break
+            with span("engine.decode_step", active=len(self.slot_state)):
+                built = self._decode_step() or built
+            steps += 1
+        if self.obs is not None and steps:
+            t_end = self.now()
+            self.obs.event("decode", t_tick0, dur=max(t_end - t_tick0, 0.0),
+                           replica_id=self.e.engine_id,
+                           data={"batch": batch0, "steps": steps})
+            self.obs.gauge("kv_occupancy", v=self.pool.utilization)
+            self.obs.gauge("engine_slots_active",
+                           v=float(len(self.slot_state)))
+            self.obs.inc("engine_compile_cache_total",
+                         {"kind": "decode",
+                          "hit": "false" if built else "true"})
+            # Per-step calibration sample against the tick-start batch.
+            # A tick that built decode_fn has its compilation in the wall —
+            # skip it, like every other building call's timing here.
+            if self.cost is not None and not built and batch0 > 0:
+                self.obs.calibrate(
+                    "decode_step",
+                    self.cost.decode_step_time(batch0, kv0),
+                    max((t_end - t_tick0) / steps, 1e-9))
+
+    def _decode_step(self) -> bool:
+        """One decode step over every active slot: grow their KV, run
+        ``_decode_fn``, sample and read back the next tokens, and advance
+        or finish each slot.  Returns whether the call built its program."""
+        with span("engine.decode_dispatch"):
             # paged growth accounting (+ LIFO recompute preemption)
             for slot in sorted(self.slot_state, reverse=True):
                 st = self.slot_state[slot]
@@ -1000,52 +1042,32 @@ class ServingEngine:
                     # else: single sequence — let it run (pool undersized)
             toks = self._put(self.last_tokens)
             pos = self._put(self.slot_pos)
+            builds = program_builds()
             logits, self.caches = self._decode_jit(self.params, toks,
                                                    self.caches, pos)
+            built = program_builds() != builds
+        with span("engine.sample"):
             self._key, sk = jax.random.split(self._key)
             nxt = np.asarray(sample_tokens(logits, sk,
                                            temperature=self.e.temperature))
-            t = self.now()
-            steps += 1
-            done = []
-            for slot, st in self.slot_state.items():
-                self.slot_pos[slot] += 1
-                self.last_tokens[slot, 0] = nxt[slot, 0]
-                self.tokens_out += 1
-                self.output_tokens.setdefault(
-                    st.req.request_id, []).append(int(nxt[slot, 0]))
-                st.req.generated += 1
-                st.budget_left -= 1
-                if self._slot_last_tok[slot] >= 0:
-                    self.decode_gaps.append(t - self._slot_last_tok[slot])
-                self._slot_last_tok[slot] = t
-                if st.budget_left <= 0 or self.slot_pos[slot] >= self.e.s_max - 1:
-                    done.append(slot)
-            for slot in done:
-                self._finish_slot(slot)
-        if self.obs is not None and steps:
-            t_end = self.now()
-            self.obs.event("decode", t_tick0, dur=max(t_end - t_tick0, 0.0),
-                           replica_id=self.e.engine_id,
-                           data={"batch": batch0, "steps": steps})
-            self.obs.gauge("kv_occupancy", v=self.pool.utilization)
-            self.obs.gauge("engine_slots_active",
-                           v=float(len(self.slot_state)))
-            self.obs.inc("engine_compile_cache_total",
-                         {"kind": "decode",
-                          "hit": "true" if self._decode_compiled
-                          else "false"})
-            # Per-step calibration sample against the tick-start batch.
-            # The first tick's wall includes decode_fn compilation — skip
-            # it, like every other fresh-JIT timing in this file.
-            if (self.cost is not None and self._decode_compiled
-                    and batch0 > 0):
-                self.obs.calibrate(
-                    "decode_step",
-                    self.cost.decode_step_time(batch0, kv0),
-                    max((t_end - t_tick0) / steps, 1e-9))
-        if steps:
-            self._decode_compiled = True
+        t = self.now()
+        done = []
+        for slot, st in self.slot_state.items():
+            self.slot_pos[slot] += 1
+            self.last_tokens[slot, 0] = nxt[slot, 0]
+            self.tokens_out += 1
+            self.output_tokens.setdefault(
+                st.req.request_id, []).append(int(nxt[slot, 0]))
+            st.req.generated += 1
+            st.budget_left -= 1
+            if self._slot_last_tok[slot] >= 0:
+                self.decode_gaps.append(t - self._slot_last_tok[slot])
+            self._slot_last_tok[slot] = t
+            if st.budget_left <= 0 or self.slot_pos[slot] >= self.e.s_max - 1:
+                done.append(slot)
+        for slot in done:
+            self._finish_slot(slot)
+        return built
 
     def _preempt_slot(self, slot: int, cause: str = "kv_pressure") -> None:
         st = self.slot_state.pop(slot)

@@ -379,6 +379,24 @@ class TestTraceSummaryTool:
         out = capsys.readouterr().out
         assert "stages" in out
 
+    def test_stage_occupancy_groups_profiler_spans(self):
+        """The tool reads the stage map from the obs plane, so the
+        profiler's engine spans group with the ring's own."""
+        ts = _load_tool("trace_summary")
+        assert ts.SPAN_STAGES is SPAN_STAGES
+        events = [
+            {"name": "engine.prefill", "ph": "X", "ts": 0.0, "dur": 3e4,
+             "pid": 0, "tid": 0},
+            {"name": "engine.chunk", "ph": "X", "ts": 4e4, "dur": 2e4,
+             "pid": 0, "tid": 0},
+            {"name": "engine.decode_step", "ph": "X", "ts": 7e4,
+             "dur": 5e4, "pid": 0, "tid": 0},
+            {"name": "engine.sample", "ph": "X", "ts": 9e4, "dur": 1e4,
+             "pid": 0, "tid": 0}]
+        occ = ts.stage_occupancy(events)
+        assert occ[0] == pytest.approx({"prefill": 0.05, "decode": 0.05,
+                                        "other": 0.01})
+
     def test_slot_events_time_ordered(self):
         ts = _load_tool("trace_summary")
         events = list(reversed(_synthetic_trace()["traceEvents"]))
@@ -534,6 +552,29 @@ class TestEngineTraceAndCalibration:
         assert "engine_compile_cache_total" in snap["counters"]
         assert "radix_probe_total" in snap["counters"]
         assert "engine_chunk_width_tokens" in snap["histograms"]
+
+    @pytest.mark.parametrize("chunk", [None, 32], ids=["bucketed", "chunked"])
+    def test_compile_cache_counter_counts_program_builds(self, model, chunk):
+        """The first call of a shape builds its program (hit="false"); the
+        same shape again runs what was built (hit="true")."""
+        cfg, params = model
+        obs = Observability.enabled()
+        eng = _engine(cfg, params, obs, chunk=chunk)
+        kind = "chunk" if chunk else "prefill"
+        counted = lambda kind, hit: obs.metrics.counter_value(  # noqa: E731
+            "engine_compile_cache_total", {"kind": kind, "hit": hit})
+        reqs = _requests(cfg, n=4, seed=6)
+        hits = []
+        for r in (reqs[0], reqs[3]):      # one shape, one request at a time
+            eng.add_request(r)
+            while eng.has_work():
+                eng.tick()
+            assert counted(kind, "false") == 1
+            assert counted("decode", "false") == 1
+            hits.append((counted(kind, "true"), counted("decode", "true")))
+        assert hits[1][0] > hits[0][0] and hits[1][1] > hits[0][1]
+        if chunk is None:
+            assert hits[0][0] == 0
 
     def test_heartbeat_feeds_health_monitor(self, model):
         cfg, params = model

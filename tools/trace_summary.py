@@ -14,7 +14,9 @@ Reports the top-N slowest requests (arrival → finish) with their
 wait / prefill / decode stage split, the per-stage aggregate breakdown,
 and per-replica engine occupancy from the spans — both per span name and
 grouped by stage (the engine's ``chunk`` / ``recompute`` spans are
-prefill-stage work, ``attach`` is the radix prefix-KV copy).  ``--slot``
+prefill-stage work, ``attach`` is the radix prefix-KV copy; the profiler's
+``engine.prefill`` / ``engine.chunk`` / ``engine.decode_step`` spans group
+with them).  ``--slot``
 prints one engine slot's lifecycle (every span and instant carrying that
 slot), mirroring ``--request``.  CI runs this as a smoke check over the
 quick-bench trace artifacts.
@@ -26,14 +28,12 @@ import argparse
 import json
 import sys
 from collections import defaultdict
+from pathlib import Path
 
-# Span-name -> stage grouping; mirrors repro.obs.trace.SPAN_STAGES (this
-# tool stays stdlib-only, so the map is duplicated rather than imported —
-# keep the two in sync).  Unknown span names group under "other".
-SPAN_STAGES = {
-    "prefill": "prefill", "chunk": "prefill", "recompute": "prefill",
-    "attach": "attach", "decode": "decode",
-}
+# Span-name -> stage grouping, from the obs plane itself (a stdlib-only
+# package).  Unknown span names group under "other".
+sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
+from repro.obs.trace import SPAN_STAGES  # noqa: E402
 
 
 def load_events(path: str) -> list[dict]:
