@@ -148,7 +148,7 @@ class ShardingPolicy:
         all-reduce; the naive head-dim contraction made GSPMD replicate the
         whole cache — see EXPERIMENTS.md §Perf).  Falls back to the feature
         dim when the sequence doesn't divide.
-        k/v (B,S,K,hd): S over tp.  MLA latent (B,S,r)/k_rope: S over tp.
+        k/v (B,K,S,hd): S over tp.  MLA latent (B,S,r)/k_rope: S over tp.
         ssm (B,H,P,N): H over tp.  conv/h states: last dim over tp."""
         stacked = _is_stacked(path)
         lead: tuple = (None,) if stacked else ()
@@ -157,9 +157,9 @@ class ShardingPolicy:
         tp = self.tp_axis
         b = self._batch(core[0])
         if name in ("k", "v"):
-            s_ax = self._ax(tp, core[1])
+            s_ax = self._ax(tp, core[2])
             hd_ax = self._ax(tp, core[3]) if s_ax is None else None
-            return P(*(lead + (b, s_ax, None, hd_ax)))
+            return P(*(lead + (b, None, s_ax, hd_ax)))
         if name in ("latent", "k_rope"):
             s_ax = self._ax(tp, core[1])
             f_ax = self._ax(tp, core[2]) if s_ax is None else None

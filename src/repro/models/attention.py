@@ -8,8 +8,11 @@
   directly) — the memory-bandwidth win MLA exists for.
 
 All softmax math in fp32 (DtypePolicy.accum); everything else in the compute
-dtype.  Shapes: x (B, S, d); caches are contiguous (B, S_max, ...) — the
-paged path lives in serving/kv_cache.py + kernels/paged_attention.
+dtype.  Shapes: x (B, S, d); caches are contiguous — GQA keys and values
+head-major (B, K, S_max, hd), the layout the decode contraction reads, MLA
+latents (B, S_max, r) — and decode writes each row's new entry in place
+(:func:`write_rows`).  The paged path lives in serving/kv_cache.py +
+kernels/paged_attention.
 """
 
 from __future__ import annotations
@@ -69,8 +72,60 @@ def kv_cache_spec(cfg: ModelConfig, batch: int, s_max: int, dtype):
     if cfg.use_mla:
         return {"latent": ((batch, s_max, cfg.kv_lora_rank), dtype),
                 "k_rope": ((batch, s_max, cfg.rope_head_dim), dtype)}
-    return {"k": ((batch, s_max, cfg.n_kv_heads, cfg.head_dim), dtype),
-            "v": ((batch, s_max, cfg.n_kv_heads, cfg.head_dim), dtype)}
+    return {"k": ((batch, cfg.n_kv_heads, s_max, cfg.head_dim), dtype),
+            "v": ((batch, cfg.n_kv_heads, s_max, cfg.head_dim), dtype)}
+
+
+# Time (sequence) axis of each decode-cache leaf within one layer's cache,
+# batch first: GQA k/v (B, K, T, hd), MLA latent/k_rope (B, T, r).
+TIME_AXIS = {"k": 2, "v": 2, "latent": 1, "k_rope": 1}
+
+
+def layer_view(buf, layer):
+    """One layer's cache: ``buf`` itself, or its ``layer`` index along a
+    stacked (L, ...) buffer."""
+    if layer is None:
+        return buf
+    return jax.lax.dynamic_index_in_dim(buf, layer, 0, keepdims=False)
+
+
+def write_rows(buf, rows, pos, t_axis: int, layer=None):
+    """Write each batch row's entry for one position into a decode cache.
+
+    ``buf`` is one layer's cache (B, ...) or, with ``layer``, the stacked
+    (L, B, ...) cache of a layer scan; ``rows`` (B, ...) is one position's
+    entry (the layer shape without its time axis ``t_axis``); ``pos`` holds
+    each row's time index (B,), or one index shared by every row (the dry
+    run's), which the write clamps into the cache.  The writes are
+    dynamic-update-slices of single rows, so a donated or loop-carried
+    buffer is updated where it lies and nothing else of it is copied.
+
+    A row whose index lies outside [0, T) writes nothing: its write is
+    pointed at that of a row inside, with that row's values.  The buffer is
+    never read here (a read makes the compiler relayout the whole cache),
+    so some row must lie inside; ``decode_step`` skips a step where none
+    does."""
+    lead = () if layer is None else (jnp.asarray(layer, jnp.int32),)
+    T = buf.shape[len(lead) + t_axis]
+    rows = jnp.expand_dims(rows.astype(buf.dtype), t_axis)
+    pos = jnp.asarray(pos, jnp.int32)
+    zero = jnp.zeros((), jnp.int32)
+
+    def put(buf, upd, b, p):
+        start = [zero] * upd.ndim
+        start[t_axis] = p
+        upd = upd.reshape((1,) * len(lead) + upd.shape)
+        return jax.lax.dynamic_update_slice(buf, upd,
+                                            lead + (b,) + tuple(start[1:]))
+
+    if pos.ndim == 0:
+        return put(buf, rows, zero, pos)
+    inside = (pos >= 0) & (pos < T)
+    src = jnp.where(inside, jnp.arange(pos.shape[0]), jnp.argmax(inside))
+    rows, pos = rows[src], pos[src]
+    for b in range(rows.shape[0]):
+        buf = put(buf, rows[b:b + 1], src[b], pos[b])
+    return buf
 
 
 # --------------------------------------------------------------------------
@@ -92,19 +147,25 @@ def _qkv(params, x, cfg: ModelConfig, positions):
 
 
 def gqa_attend(q, k, v, mask):
-    """q (B,S,H,hd), k/v (B,T,K,hd), mask (S,T) or (B,1,1,S,T)."""
+    """q (B,S,H,hd), head-major k/v (B,K,T,hd), mask (S,T) or
+    (B,1,1,S,T)."""
     B, S, H, hd = q.shape
-    K = k.shape[2]
+    K = k.shape[1]
     G = H // K
     qg = q.reshape(B, S, K, G, hd)
     scale = hd ** -0.5
-    scores = jnp.einsum("bskgh,btkh->bkgst", qg, k) * scale
+    scores = jnp.einsum("bskgh,bkth->bkgst", qg, k) * scale
     scores = scores.astype(jnp.float32)
     neg = jnp.finfo(jnp.float32).min
     scores = jnp.where(mask, scores, neg)
     probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
-    out = jnp.einsum("bkgst,btkh->bskgh", probs, v)
+    out = jnp.einsum("bkgst,bkth->bskgh", probs, v)
     return out.reshape(B, S, H * hd)
+
+
+def _head_major(t):
+    """(B, S, K, hd) -> (B, K, S, hd)."""
+    return t.transpose(0, 2, 1, 3)
 
 
 BLOCKWISE_THRESHOLD = 2048     # use blockwise attention when S exceeds this
@@ -112,7 +173,9 @@ BLOCKWISE_THRESHOLD = 2048     # use blockwise attention when S exceeds this
 
 def gqa_forward(params, x, cfg: ModelConfig, *, window: int,
                 positions, causal: bool = True, return_kv: bool = False):
-    """Full-sequence attention (train / prefill)."""
+    """Full-sequence attention (train / prefill).  ``return_kv`` also
+    returns the keys and values head-major, as the decode cache holds
+    them."""
     from .blockwise import blockwise_gqa_attend
     q, k, v = _qkv(params, x, cfg, positions)
     S = x.shape[1]
@@ -123,7 +186,9 @@ def gqa_forward(params, x, cfg: ModelConfig, *, window: int,
             k = jax.lax.with_sharding_constraint(k, P(U, None, None, None))
             v = jax.lax.with_sharding_constraint(v, P(U, None, None, None))
         out = blockwise_gqa_attend(q, k, v, causal=causal, window=window)
+        k, v = _head_major(k), _head_major(v)
     else:
+        k, v = _head_major(k), _head_major(v)
         mask = attention_mask(S, S, causal=causal, window=window)
         out = gqa_attend(q, k, v, mask)
     y = out @ params["wo"]
@@ -133,97 +198,84 @@ def gqa_forward(params, x, cfg: ModelConfig, *, window: int,
 
 
 def _pos_vec(cache_pos, B):
-    """Normalize cache_pos: scalar (dry-run serve_step) or (B,) per-row
-    (slot-based engine, sequences at different lengths)."""
+    """Per-row positions (B,) from a scalar (dry-run serve_step) or a (B,)
+    vector (slot-based engine, sequences at different lengths)."""
     p = jnp.asarray(cache_pos, dtype=jnp.int32)
-    scalar = p.ndim == 0
-    return (jnp.full((B,), p, jnp.int32) if scalar else p), scalar
+    return jnp.full((B,), p, jnp.int32) if p.ndim == 0 else p
 
 
-def _cache_write(cache_t, new_t, cache_pos, scalar):
-    """Write new_t (B,1,...) into cache_t (B,S,...) at per-row positions.
-    Scalar positions use dynamic_update_slice (cheaper HLO for the
-    dry-run); vectors use a row scatter."""
-    if scalar:
-        return jax.lax.dynamic_update_slice_in_dim(
-            cache_t, new_t.astype(cache_t.dtype),
-            jnp.asarray(cache_pos, jnp.int32).reshape(()), axis=1)
-    B = cache_t.shape[0]
-    return cache_t.at[jnp.arange(B), cache_pos].set(
-        new_t[:, 0].astype(cache_t.dtype), mode="drop")
-
-
-def gqa_decode(params, x, cache: dict, cache_pos, cfg: ModelConfig,
-               *, window: int):
-    """Single-token decode.  x (B,1,d); cache k/v (B,S_max,K,hd);
-    cache_pos: scalar int or (B,) vector — tokens already in each cache."""
+def _decode_qkv(params, x, posv, cfg: ModelConfig):
+    """One token's query (B,1,H,hd) and new key/value rows (B,K,hd)."""
     B = x.shape[0]
     H, K, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    posv, scalar = _pos_vec(cache_pos, B)
-    pos = posv[:, None]
     q = (x @ params["wq"]).reshape(B, 1, H, hd)
     k_new = (x @ params["wk"]).reshape(B, 1, K, hd)
-    v_new = (x @ params["wv"]).reshape(B, 1, K, hd)
+    v_new = (x @ params["wv"]).reshape(B, K, hd)
     if cfg.qk_norm:
         q = rms_norm(q, params["q_norm"], cfg.norm_eps)
         k_new = rms_norm(k_new, params["k_norm"], cfg.norm_eps)
+    pos = posv[:, None]
     q = apply_rope(q, pos, cfg.rope_theta)
     k_new = apply_rope(k_new, pos, cfg.rope_theta)
-    k = _cache_write(cache["k"], k_new, cache_pos if scalar else posv, scalar)
-    v = _cache_write(cache["v"], v_new, cache_pos if scalar else posv, scalar)
-    T = k.shape[1]
+    return q, k_new[:, 0], v_new
+
+
+def gqa_decode(params, x, cache: dict, cache_pos, cfg: ModelConfig,
+               *, window: int, layer=None):
+    """Single-token decode.  x (B,1,d); cache k/v (B,K,S_max,hd), or the
+    layer scan's stacked (L,B,K,S_max,hd) with ``layer``; cache_pos: scalar
+    int or (B,) vector — tokens already in each cache.  Returns the output
+    and the cache with each row's new key and value written in place."""
+    posv = _pos_vec(cache_pos, x.shape[0])
+    q, k_new, v_new = _decode_qkv(params, x, posv, cfg)
+    k = write_rows(cache["k"], k_new, cache_pos, 2, layer)
+    v = write_rows(cache["v"], v_new, cache_pos, 2, layer)
+    T = k.shape[-2]
     k_pos = jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)
     mask = k_pos <= posv[:, None]                       # (B,T) causal
     if window and window > 0:
         mask &= k_pos > (posv[:, None] - window)
-    out = gqa_attend(q, k, v, mask[:, None, None, None, :])
+    out = gqa_attend(q, layer_view(k, layer), layer_view(v, layer),
+                     mask[:, None, None, None, :])
     y = out @ params["wo"]
     return y, {"k": k, "v": v}
 
 
 def gqa_decode_ring(params, x, cache: dict, cache_pos, cfg: ModelConfig,
-                    *, window: int):
+                    *, window: int, layer=None):
     """Single-token decode with a *ring-buffer* window cache — the memory
     win that makes SWA/local layers O(window) instead of O(seq) in the
-    long_500k cell.  cache k/v: (B, W, K, hd), slot = abs_pos % W, keys are
-    stored post-RoPE so no re-rotation is needed."""
-    B = x.shape[0]
-    H, K, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    W = cache["k"].shape[1]
-    posv, scalar = _pos_vec(cache_pos, B)
-    pos = posv[:, None]
-    q = (x @ params["wq"]).reshape(B, 1, H, hd)
-    k_new = (x @ params["wk"]).reshape(B, 1, K, hd)
-    v_new = (x @ params["wv"]).reshape(B, 1, K, hd)
-    if cfg.qk_norm:
-        q = rms_norm(q, params["q_norm"], cfg.norm_eps)
-        k_new = rms_norm(k_new, params["k_norm"], cfg.norm_eps)
-    q = apply_rope(q, pos, cfg.rope_theta)
-    k_new = apply_rope(k_new, pos, cfg.rope_theta)
-    slot = jnp.mod(posv, W)
-    k = _cache_write(cache["k"], k_new, jnp.mod(cache_pos, W) if scalar
-                     else slot, scalar)
-    v = _cache_write(cache["v"], v_new, jnp.mod(cache_pos, W) if scalar
-                     else slot, scalar)
+    long_500k cell.  cache k/v: (B, K, W, hd) (stacked as in
+    :func:`gqa_decode`), slot = abs_pos % W, keys are stored post-RoPE so no
+    re-rotation is needed.  A negative position writes nothing."""
+    W = cache["k"].shape[-2]
+    posv = _pos_vec(cache_pos, x.shape[0])
+    q, k_new, v_new = _decode_qkv(params, x, posv, cfg)
+    p = jnp.asarray(cache_pos, jnp.int32)
+    slot = jnp.where(p < 0, -1, jnp.mod(p, W))
+    k = write_rows(cache["k"], k_new, slot, 2, layer)
+    v = write_rows(cache["v"], v_new, slot, 2, layer)
     # slot s holds absolute position pos - ((pos - s) mod W); valid if >= 0.
+    pos = posv[:, None]
     s_idx = jax.lax.broadcasted_iota(jnp.int32, (1, W), 1)
     abs_pos = pos - jnp.mod(pos - s_idx, W)                 # (B, W)
     mask = abs_pos >= 0
-    out = gqa_attend(q, k, v, mask[:, None, None, None, :])
+    out = gqa_attend(q, layer_view(k, layer), layer_view(v, layer),
+                     mask[:, None, None, None, :])
     y = out @ params["wo"]
     return y, {"k": k, "v": v}
 
 
 def ring_cache_from_prefill(kv: dict, window: int) -> dict:
-    """Convert full prefill k/v (B, S, K, hd) into ring-buffer layout."""
+    """Convert full prefill k/v (B, K, S, hd) into ring-buffer layout."""
     out = {}
     for name in ("k", "v"):
         t = kv[name]
-        S = t.shape[1]
+        S = t.shape[2]
         W = min(window, S) if window else S
-        last = t[:, S - W:, :, :]
+        last = t[:, :, S - W:, :]
         shift = (S - W) % W if W else 0
-        out[name] = jnp.roll(last, shift=shift, axis=1)
+        out[name] = jnp.roll(last, shift=shift, axis=2)
     return out
 
 
@@ -276,15 +328,16 @@ def mla_forward(params, x, cfg: ModelConfig, *, positions,
 
 
 def mla_decode(params, x, cache: dict, cache_pos, cfg: ModelConfig,
-               *, window: int = 0):
+               *, window: int = 0, layer=None):
     """Absorbed-MLA decode: scores hit the cached latent directly —
     q_eff = q_nope @ W_uk (per head) → (B,H,r); attention over latent (B,T,r);
     output = (probs @ latent) @ W_uv.  KV traffic = r + rd per token instead
-    of 2·H·hd — the MLA serving win."""
+    of 2·H·hd — the MLA serving win.  Stacked caches with ``layer`` as in
+    :func:`gqa_decode`."""
     B = x.shape[0]
     H, hd = cfg.n_heads, cfg.head_dim
     r, rd, vd = cfg.kv_lora_rank, cfg.rope_head_dim, cfg.v_head_dim
-    posv, scalar = _pos_vec(cache_pos, B)
+    posv = _pos_vec(cache_pos, B)
     pos = posv[:, None]
     q = (x @ params["wq"]).reshape(B, 1, H, hd + rd)
     q_nope, q_rope = q[..., :hd], q[..., hd:]
@@ -292,10 +345,11 @@ def mla_decode(params, x, cache: dict, cache_pos, cfg: ModelConfig,
     c_new = rms_norm(x @ params["w_dkv"], params["kv_norm"], cfg.norm_eps)
     k_rope_new = apply_rope((x @ params["w_krope"]).reshape(B, 1, 1, rd),
                             pos, cfg.rope_theta)[:, 0, 0]      # (B,rd)
-    latent = _cache_write(cache["latent"], c_new,
-                          cache_pos if scalar else posv, scalar)
-    k_rope = _cache_write(cache["k_rope"], k_rope_new[:, None, :],
-                          cache_pos if scalar else posv, scalar)
+    latent_buf = write_rows(cache["latent"], c_new[:, 0], cache_pos, 1,
+                            layer)
+    k_rope_buf = write_rows(cache["k_rope"], k_rope_new, cache_pos, 1, layer)
+    latent = layer_view(latent_buf, layer)
+    k_rope = layer_view(k_rope_buf, layer)
     # absorb: q_eff[b,h,r] = q_nope[b,h,:] @ W_uk[:, h, :]  (W_uk: (r, H, hd))
     w_uk = params["w_uk"].reshape(r, H, hd)
     q_eff = jnp.einsum("bhd,rhd->bhr", q_nope[:, 0], w_uk)
@@ -314,7 +368,7 @@ def mla_decode(params, x, cache: dict, cache_pos, cfg: ModelConfig,
     w_uv = params["w_uv"].reshape(r, H, vd)
     out = jnp.einsum("bhr,rhd->bhd", ctx, w_uv).reshape(B, 1, H * vd)
     y = out @ params["wo"]
-    return y, {"latent": latent, "k_rope": k_rope}
+    return y, {"latent": latent_buf, "k_rope": k_rope_buf}
 
 
 # --------------------------------------------------------------------------
@@ -328,7 +382,7 @@ def gqa_chunk_decode(params, x, cache: dict, pos0, cfg: ModelConfig,
     causally over everything resident up to each query.  This is the one
     primitive both chunked prefill and radix prefix reuse need — a prefill
     that *starts at an offset* (pos0=0 degrades to plain prefill; C=1 to
-    single-token decode).  x (B,C,d); cache k/v (B,S_max,K,hd); pos0 is a
+    single-token decode).  x (B,C,d); cache k/v (B,K,S_max,hd); pos0 is a
     scalar shared by every row (the engine runs one slot per chunk call).
     Ring-buffer (windowed) caches are NOT supported: a later chunk token
     would overwrite the ring slot an earlier in-chunk query still needs —
@@ -347,10 +401,10 @@ def gqa_chunk_decode(params, x, cache: dict, pos0, cfg: ModelConfig,
     q = apply_rope(q, pos_b, cfg.rope_theta)
     k_new = apply_rope(k_new, pos_b, cfg.rope_theta)
     k = jax.lax.dynamic_update_slice_in_dim(
-        cache["k"], k_new.astype(cache["k"].dtype), p0, axis=1)
+        cache["k"], _head_major(k_new).astype(cache["k"].dtype), p0, axis=2)
     v = jax.lax.dynamic_update_slice_in_dim(
-        cache["v"], v_new.astype(cache["v"].dtype), p0, axis=1)
-    T = k.shape[1]
+        cache["v"], _head_major(v_new).astype(cache["v"].dtype), p0, axis=2)
+    T = k.shape[2]
     k_pos = jax.lax.broadcasted_iota(jnp.int32, (C, T), 1)
     mask = k_pos <= positions[:, None]                         # (C,T) causal
     if window and window > 0:
@@ -425,11 +479,13 @@ def attn_forward(params, x, cfg: ModelConfig, kind: str, positions,
                        causal=cfg.causal, return_kv=return_kv)
 
 
-def attn_decode(params, x, cache, cache_pos, cfg: ModelConfig, kind: str):
+def attn_decode(params, x, cache, cache_pos, cfg: ModelConfig, kind: str,
+                layer=None):
     w = window_for(cfg, kind)
     if cfg.use_mla:
-        return mla_decode(params, x, cache, cache_pos, cfg, window=w)
-    return gqa_decode(params, x, cache, cache_pos, cfg, window=w)
+        return mla_decode(params, x, cache, cache_pos, cfg, window=w,
+                          layer=layer)
+    return gqa_decode(params, x, cache, cache_pos, cfg, window=w, layer=layer)
 
 
 def attn_chunk_decode(params, x, cache, pos0, cfg: ModelConfig, kind: str):

@@ -20,8 +20,9 @@ import jax.numpy as jnp
 
 from ..configs.base import ModelConfig
 from .common import DtypePolicy, embed_init, dense_init, rms_norm
-from .transformer import (MoECtx, constrain_x, init_stack, init_stack_cache,
-                          stack_chunk, stack_decode, stack_forward)
+from .transformer import (MoECtx, constrain_x, decode_rows_inside,
+                          init_stack, init_stack_cache, stack_chunk,
+                          stack_decode, stack_forward)
 
 AUX_LOSS_WEIGHT = 0.01
 
@@ -137,18 +138,31 @@ def decode_step(params, tokens, caches, cache_pos, cfg: ModelConfig,
                 moe_ctx: MoECtx = MoECtx(), *,
                 policy: DtypePolicy = DtypePolicy.serve()):
     """One token for every sequence.  tokens (B,1) i32; cache_pos scalar i32
-    (tokens already in cache).  Returns (logits (B,1,V), new caches)."""
-    x = jnp.take(params["embed"], tokens, axis=0).astype(policy.compute)
-    if cfg.tie_embeddings:
-        x = x * jnp.sqrt(float(cfg.d_model)).astype(policy.compute)
-    h, new_caches = stack_decode(params["blocks"], x, caches, cache_pos,
-                                 cfg, moe_ctx)
-    h = rms_norm(h, params["final_norm"], cfg.norm_eps)
-    w_head = _unembed(params, cfg)
-    logits = (h.astype(w_head.dtype) @ w_head).astype(jnp.float32)
-    if cfg.logit_softcap:
-        logits = cfg.logit_softcap * jnp.tanh(logits / cfg.logit_softcap)
-    return logits, new_caches
+    or (B,) per-row positions (tokens already in each row's cache).
+    Returns (logits (B,1,V), new caches): each row's new entries written in
+    place, none for a row whose position lies outside the cache."""
+    def step(caches):
+        x = jnp.take(params["embed"], tokens, axis=0).astype(policy.compute)
+        if cfg.tie_embeddings:
+            x = x * jnp.sqrt(float(cfg.d_model)).astype(policy.compute)
+        h, new_caches = stack_decode(params["blocks"], x, caches, cache_pos,
+                                     cfg, moe_ctx)
+        h = rms_norm(h, params["final_norm"], cfg.norm_eps)
+        w_head = _unembed(params, cfg)
+        logits = (h.astype(w_head.dtype) @ w_head).astype(jnp.float32)
+        if cfg.logit_softcap:
+            logits = cfg.logit_softcap * jnp.tanh(logits / cfg.logit_softcap)
+        return logits, new_caches
+
+    pos = jnp.asarray(cache_pos, jnp.int32)
+    if pos.ndim == 0:
+        return step(caches)
+    # Rows outside the caches write nothing only beside a row inside them
+    # (attention.write_rows); with none, the step leaves the caches alone.
+    def skip(caches):
+        return jnp.zeros(tokens.shape + (cfg.vocab_size,), jnp.float32), caches
+    return jax.lax.cond(decode_rows_inside(cfg, caches, pos), step, skip,
+                        caches)
 
 
 def chunk_step(params, tokens, caches, pos0, cfg: ModelConfig,
@@ -186,7 +200,7 @@ def pad_prefill_caches(caches: dict, cfg: ModelConfig, target_len: int) -> dict:
     full/MLA caches get zero-padding on the sequence axis; ring caches grow
     to the window size (slot semantics preserved — see gqa_decode_ring);
     SSM/RG-LRU states are O(1) and pass through."""
-    from .attention import window_for
+    from .attention import TIME_AXIS, window_for
     from .transformer import _uses_ring, layer_kinds, stack_layout
 
     head, n_periods, tail = stack_layout(cfg)
@@ -195,7 +209,6 @@ def pad_prefill_caches(caches: dict, cfg: ModelConfig, target_len: int) -> dict:
     def pad_entry(c: dict, kind: str, stacked: bool) -> dict:
         if kind not in ("attn", "local", "global"):
             return c
-        ax = 2 if stacked else 1
         if not cfg.use_mla and _uses_ring(cfg, kind):
             w = window_for(cfg, kind)
             tgt = min(w, target_len) if w else target_len
@@ -203,6 +216,7 @@ def pad_prefill_caches(caches: dict, cfg: ModelConfig, target_len: int) -> dict:
             tgt = target_len
         out = {}
         for name, t in c.items():
+            ax = TIME_AXIS[name] + int(stacked)
             pad = tgt - t.shape[ax]
             if pad > 0:
                 widths = [(0, 0)] * t.ndim

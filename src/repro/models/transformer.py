@@ -11,6 +11,9 @@ gemma: rglru, rglru, local).  The stack is executed as
     tail blocks (unrolled)   — n_layers % period remainder
 
 Caches mirror this layout: {"head": [..], "stack": {slot_i: stacked}, "tail": [..]}.
+Decode carries the stacked caches through the scan and writes each layer's
+new rows in place at the layer index (:func:`stack_decode`), so a donated
+cache is never copied.
 """
 
 from __future__ import annotations
@@ -22,9 +25,9 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ModelConfig
-from .attention import (attn_chunk_decode, attn_decode, attn_forward,
-                        gqa_decode_ring, init_attention,
-                        ring_cache_from_prefill, window_for)
+from .attention import (TIME_AXIS, attn_chunk_decode, attn_decode,
+                        attn_forward, gqa_decode_ring, init_attention,
+                        layer_view, ring_cache_from_prefill, window_for)
 from .common import rms_norm
 from .mlp import init_mlp, mlp_forward
 from .moe import aux_load_balance_loss, init_moe, moe_forward
@@ -151,20 +154,30 @@ def block_forward(bp: dict, x, cfg: ModelConfig, kind: str, positions,
 
 
 def block_decode(bp: dict, x, cache, cache_pos, cfg: ModelConfig, kind: str,
-                 use_moe: bool, moe_ctx: MoECtx):
-    """One-token decode through a block.  Returns (x, new_cache)."""
+                 use_moe: bool, moe_ctx: MoECtx, layer=None):
+    """One-token decode through a block.  ``cache`` is the block's own, or
+    with ``layer`` the layer scan's stacked caches, of which only index
+    ``layer`` is read and written.  Returns (x, updated cache): attention
+    writes the new rows in place, recurrent state is replaced whole."""
     h = rms_norm(x, bp["ln1"], cfg.norm_eps)
     if kind in ATTN_KINDS:
         if not cfg.use_mla and _uses_ring(cfg, kind):
             mix, new_cache = gqa_decode_ring(bp["mixer"], h, cache, cache_pos,
-                                             cfg, window=window_for(cfg, kind))
+                                             cfg, window=window_for(cfg, kind),
+                                             layer=layer)
         else:
             mix, new_cache = attn_decode(bp["mixer"], h, cache, cache_pos,
-                                         cfg, kind)
-    elif kind == "ssm":
-        mix, new_cache = ssm_decode(bp["mixer"], h, cache, cfg)
+                                         cfg, kind, layer=layer)
     else:
-        mix, new_cache = rglru_decode(bp["mixer"], h, cache, cfg)
+        step = ssm_decode if kind == "ssm" else rglru_decode
+        state = {n: layer_view(t, layer) for n, t in cache.items()}
+        mix, new_state = step(bp["mixer"], h, state, cfg)
+        if layer is None:
+            new_cache = new_state
+        else:
+            new_cache = {n: jax.lax.dynamic_update_index_in_dim(
+                t, new_state[n].astype(t.dtype), layer, 0)
+                for n, t in cache.items()}
     x = x + mix.astype(x.dtype)
     if "mlp" in bp:
         h2 = rms_norm(x, bp["ln2"], cfg.norm_eps)
@@ -224,7 +237,7 @@ def init_block_cache(cfg: ModelConfig, kind: str, batch: int, s_max: int,
                     "k_rope": jnp.zeros((batch, s_max, cfg.rope_head_dim), dtype)}
         w = window_for(cfg, kind)
         length = min(w, s_max) if _uses_ring(cfg, kind) and w else s_max
-        shape = (batch, length, cfg.n_kv_heads, cfg.head_dim)
+        shape = (batch, cfg.n_kv_heads, length, cfg.head_dim)
         return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
     if kind == "ssm":
         return init_ssm_cache(cfg, batch, dtype)
@@ -353,7 +366,11 @@ def stack_forward(params: dict, x, cfg: ModelConfig, positions,
 
 def stack_decode(params: dict, x, caches: dict, cache_pos, cfg: ModelConfig,
                  moe_ctx: MoECtx):
-    """One-token decode through the whole stack.  Returns (x, new_caches)."""
+    """One-token decode through the whole stack.  Returns (x, new_caches).
+
+    The stacked caches ride in the scan's carry with the layer index, and
+    each layer writes only its new rows at that index: with the caches
+    donated, the step updates them where they lie."""
     head, n_periods, tail = stack_layout(cfg)
     kinds = layer_kinds(cfg)
     use_moe = cfg.n_experts > 0
@@ -365,19 +382,19 @@ def stack_decode(params: dict, x, caches: dict, cache_pos, cfg: ModelConfig,
         new_caches["head"].append(c)
 
     if n_periods > 0:
-        def scan_body(x, inp):
+        def scan_body(carry, pp):
+            x, layer, stack = carry
             x = constrain_x(x, moe_ctx)
-            pp, pc = inp
-            ncs = {}
+            stack = dict(stack)
             for i, kind in enumerate(cfg.pattern):
-                x, nc = block_decode(pp[f"slot_{i}"], x, pc[f"slot_{i}"],
-                                     cache_pos, cfg, kind, use_moe, moe_ctx)
-                ncs[f"slot_{i}"] = nc
-            return x, ncs
+                x, stack[f"slot_{i}"] = block_decode(
+                    pp[f"slot_{i}"], x, stack[f"slot_{i}"], cache_pos, cfg,
+                    kind, use_moe, moe_ctx, layer=layer)
+            return (x, layer + 1, stack), None
 
-        x, stack_caches = jax.lax.scan(
-            scan_body, x, (params["stack"], caches["stack"]))
-        new_caches["stack"] = stack_caches
+        (x, _, new_caches["stack"]), _ = jax.lax.scan(
+            scan_body, (x, jnp.zeros((), jnp.int32), caches["stack"]),
+            params["stack"])
 
     for i in range(tail):
         kind = cfg.pattern[i % len(cfg.pattern)]
@@ -386,6 +403,27 @@ def stack_decode(params: dict, x, caches: dict, cache_pos, cfg: ModelConfig,
         new_caches["tail"].append(c)
 
     return x, new_caches
+
+
+def decode_rows_inside(cfg: ModelConfig, caches: dict, pos):
+    """Whether some row's position (``pos``, (B,)) lies inside every
+    attention cache of the stack: [0, T) of a full cache, >= 0 of a ring
+    (which any such position maps into).  ``attention.write_rows`` points
+    the writes of rows outside at such a row's."""
+    head, n_periods, tail = stack_layout(cfg)
+    kinds = layer_kinds(cfg)
+    blocks = [(kinds[i], c, 0) for i, c in enumerate(caches["head"])]
+    if n_periods > 0:
+        blocks += [(kind, caches["stack"][f"slot_{i}"], 1)
+                   for i, kind in enumerate(cfg.pattern)]
+    blocks += [(cfg.pattern[i % len(cfg.pattern)], c, 0)
+               for i, c in enumerate(caches["tail"])]
+    inside = pos >= 0
+    for kind, c, stacked in blocks:
+        if kind in ATTN_KINDS and (cfg.use_mla or not _uses_ring(cfg, kind)):
+            for name, t in c.items():
+                inside &= pos < t.shape[TIME_AXIS[name] + stacked]
+    return jnp.any(inside)
 
 
 def stack_chunk(params: dict, x, caches: dict, pos0, cfg: ModelConfig,

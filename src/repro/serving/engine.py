@@ -64,6 +64,7 @@ from ..models.common import DtypePolicy
 from ..obs.trace import program_builds, span
 from ..models.model import (_embed_inputs, _unembed, chunk_step, decode_step,
                             init_decode_caches, pad_prefill_caches)
+from ..models.attention import TIME_AXIS
 from ..models.common import rms_norm
 from ..models.transformer import MoECtx, stack_forward, supports_chunked_decode
 from .kv_cache import BlockPool, SlotAllocator
@@ -96,6 +97,14 @@ class EngineConfig:
     # the key heartbeats carry).  Default 0 matches the single-engine trace
     # layout that predates multi-engine observability.
     engine_id: int = 0
+
+
+def _block_index(name: str, stacked: bool, slot: int, lo: int, hi: int):
+    """Index of one slot's positions [lo, hi) in a cache leaf: the slot on
+    the batch axis (the second, after the layer scan's), the span on the
+    leaf's time axis (``attention.TIME_AXIS``)."""
+    lead = (slice(None), slot) if stacked else (slot,)
+    return lead + (slice(None),) * (TIME_AXIS[name] - 1) + (slice(lo, hi),)
 
 
 @dataclass
@@ -236,7 +245,13 @@ class ServingEngine:
         self.prefill_batches = 0
         self.padded_tokens = 0
         self.real_tokens = 0
-        self._decode_jit = jax.jit(self._decode_fn)
+        # The decode step donates the caches and writes each slot's new rows
+        # where they lie: no code may hold a leaf of ``self.caches`` across
+        # it.  Steps counted by whether the runtime reused the donated
+        # buffers or fell back to a copy (the input then stays alive).
+        self.decode_in_place = 0
+        self.decode_copied = 0
+        self._decode_jit = jax.jit(self._decode_fn, donate_argnums=(2,))
         self._prefill_jits: dict = {}
         self._t0 = time.monotonic()
 
@@ -326,38 +341,35 @@ class ServingEngine:
         lo = block_idx * self.e.block_size
         hi = lo + self.e.block_size
 
-        def flat(t):
-            return np.asarray(t[slot, lo:hi])
+        def take(c: dict, stacked: bool) -> dict:
+            return {n: np.asarray(t[_block_index(n, stacked, slot, lo, hi)])
+                    for n, t in c.items()}
 
-        def stacked(t):
-            return np.asarray(t[:, slot, lo:hi])
-
-        out = {"head": [jax.tree.map(flat, c) for c in self.caches["head"]],
-               "tail": [jax.tree.map(flat, c) for c in self.caches["tail"]]}
+        out = {"head": [take(c, False) for c in self.caches["head"]],
+               "tail": [take(c, False) for c in self.caches["tail"]]}
         if "stack" in self.caches:
-            out["stack"] = jax.tree.map(stacked, self.caches["stack"])
+            out["stack"] = {k: take(c, True)
+                            for k, c in self.caches["stack"].items()}
         return out
 
     def _write_block(self, slot: int, block_idx: int, block_kv: dict) -> None:
         """Copy one cached KV block (host numpy rows) into a slot's span —
         the radix attach: cached prefix blocks land without recompute."""
         lo = block_idx * self.e.block_size
+        hi = lo + self.e.block_size
 
-        def flat_at(dst, src):
-            return dst.at[slot, lo:lo + src.shape[0]].set(
-                self._put(src).astype(dst.dtype))
-
-        def stacked_at(dst, src):
-            return dst.at[:, slot, lo:lo + src.shape[1]].set(
-                self._put(src).astype(dst.dtype))
+        def put(dst: dict, src: dict, stacked: bool) -> dict:
+            return {n: t.at[_block_index(n, stacked, slot, lo, hi)].set(
+                        self._put(src[n]).astype(t.dtype))
+                    for n, t in dst.items()}
 
         new = dict(self.caches)
-        new["head"] = [jax.tree.map(flat_at, d, s)
+        new["head"] = [put(d, s, False)
                        for d, s in zip(self.caches["head"], block_kv["head"])]
         if "stack" in self.caches:
-            new["stack"] = jax.tree.map(stacked_at, self.caches["stack"],
-                                        block_kv["stack"])
-        new["tail"] = [jax.tree.map(flat_at, d, s)
+            new["stack"] = {k: put(d, block_kv["stack"][k], True)
+                            for k, d in self.caches["stack"].items()}
+        new["tail"] = [put(d, s, False)
                        for d, s in zip(self.caches["tail"], block_kv["tail"])]
         self.caches = new
 
@@ -1043,9 +1055,16 @@ class ServingEngine:
             toks = self._put(self.last_tokens)
             pos = self._put(self.slot_pos)
             builds = program_builds()
+            donated = jax.tree.leaves(self.caches)[0]
             logits, self.caches = self._decode_jit(self.params, toks,
                                                    self.caches, pos)
             built = program_builds() != builds
+            in_place = donated.is_deleted()
+            self.decode_in_place += in_place
+            self.decode_copied += not in_place
+            if self.obs is not None:
+                self.obs.inc("engine_decode_in_place_total",
+                             {"donated": "true" if in_place else "false"})
         with span("engine.sample"):
             self._key, sk = jax.random.split(self._key)
             nxt = np.asarray(sample_tokens(logits, sk,
@@ -1168,6 +1187,8 @@ class ServingEngine:
             "tok_per_s": toks / max(elapsed, 1e-9),
             "req_per_s": len(self.finished) / max(elapsed, 1e-9),
             "preemptions": self.preemptions,
+            "decode_in_place": self.decode_in_place,
+            "decode_copied": self.decode_copied,
             "prefill_batches": self.prefill_batches,
             "padding_waste": (1.0 - self.real_tokens
                               / max(self.padded_tokens, 1)),

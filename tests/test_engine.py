@@ -89,6 +89,54 @@ def test_engine_device_commits_state_and_keeps_tokens(model, chunked):
     assert outs[None] == outs[dev]
 
 
+def test_engine_decode_donates_caches_in_place(model):
+    """The decode step donates the engine's caches: its input leaves are
+    deleted, every step counts as in place (the obs counter agrees), and
+    the greedy tokens equal a loop over ``decode_step`` without donation
+    from the same state."""
+    import jax.numpy as jnp
+    from repro.models import decode_step
+    from repro.obs import Observability
+    cfg, params = model
+    steps = 5
+    obs = Observability.enabled()
+    eng = ServingEngine(cfg, params, FCFSScheduler(),
+                        EngineConfig(max_slots=4, s_max=64,
+                                     kv_pool_tokens=1024, buckets=(32,),
+                                     decode_steps_per_tick=steps), obs=obs)
+    for i, n in enumerate((5, 17, 30)):
+        eng.add_request(Request(
+            prompt_len=n, arrival_time=0.0, max_new_tokens=steps + 2,
+            prompt_tokens=((np.arange(n) * 3 + i) % cfg.vocab_size)
+            .astype(np.int32)))
+    eng._admit(eng.now())
+    assert len(eng.slot_state) == 3
+    held = jax.tree.leaves(eng.caches)
+    caches = jax.tree.map(jnp.copy, eng.caches)
+    toks, pos = eng.last_tokens.copy(), eng.slot_pos.copy()
+    eng._decode_tick()
+
+    assert all(x.is_deleted() for x in held)
+    assert (eng.decode_in_place, eng.decode_copied) == (steps, 0)
+    assert eng.stats()["decode_in_place"] == steps
+    count = lambda donated: obs.metrics.counter_value(  # noqa: E731
+        "engine_decode_in_place_total", {"donated": donated})
+    assert (count("true"), count("false")) == (steps, 0)
+
+    step = jax.jit(lambda p, t, c, q: decode_step(
+        p, t, c, q, cfg, eng.moe_ctx, policy=eng.policy))
+    want = []
+    for _ in range(steps):
+        logits, caches = step(eng.params, toks, caches, pos)
+        toks = np.asarray(jnp.argmax(logits[:, 0], axis=-1),
+                          np.int32)[:, None]
+        want.append(toks[:, 0])
+        pos = pos + 1
+    for slot, st in eng.slot_state.items():
+        assert eng.output_tokens[st.req.request_id][1:] == \
+            [int(w[slot]) for w in want]
+
+
 def test_engine_outputs_independent_of_scheduler(model):
     """Greedy decoding: each request's tokens must not depend on the
     admission order (isolation of slots + per-row positions)."""

@@ -83,6 +83,91 @@ def test_decode_matches_prefill(arch):
     np.testing.assert_allclose(np.asarray(dec), np.asarray(full), atol=2e-3)
 
 
+DECODERS = [a for a in ARCHS if not get_smoke_config(a).is_encoder_only]
+ROW_LENS = (37, 9, 22)   # row 0 lies past every smoke window: its ring wrapped
+ROW_T = 48               # positions a full cache holds
+
+
+def _row_lens(cfg):
+    """SSD prefills one chunk (32 in the smoke config) or whole chunks."""
+    return (31,) + ROW_LENS[1:] if cfg.family == "ssm" else ROW_LENS
+
+
+def _prefill_rows(params, cfg, moe, toks, lens):
+    """Each row prefilled alone to its own length: the rows' caches padded
+    to ``ROW_T`` and joined on the batch axis (axis 1 of the scan's stacked
+    caches), and each row's logits after its next token."""
+    def run(x):
+        batch = ({"embeddings": jnp.take(params["embed"], x, axis=0)}
+                 if cfg.input_mode == "embeddings" else {"tokens": x})
+        return prefill(params, batch, cfg, moe, policy=F32)
+
+    caches, want = [], []
+    for b, n in enumerate(lens):
+        _, c = run(toks[b:b + 1, :n])
+        caches.append(pad_prefill_caches(c, cfg, ROW_T))
+        want.append(run(toks[b:b + 1, :n + 1])[0][0])
+    join = lambda path, *t: jnp.concatenate(  # noqa: E731
+        t, axis=int(path[0].key == "stack"))
+    return jax.tree_util.tree_map_with_path(join, *caches), jnp.stack(want)
+
+
+def _rows_setup(arch):
+    cfg = get_smoke_config(arch)
+    params = init_params(jax.random.PRNGKey(1), cfg)
+    lens = _row_lens(cfg)
+    toks = jax.random.randint(jax.random.PRNGKey(2),
+                              (len(lens), max(lens) + 1), 0, cfg.vocab_size)
+    moe = MoECtx(impl="dense" if cfg.n_experts else "dropping")
+    caches, want = _prefill_rows(params, cfg, moe, toks, lens)
+    nxt = toks[jnp.arange(len(lens)), jnp.asarray(lens)][:, None]
+    return cfg, params, moe, caches, want, nxt, lens
+
+
+@pytest.mark.parametrize("arch", DECODERS)
+def test_decode_rows_match_prefill(arch):
+    """Rows at their own positions (a (B,) ``cache_pos``, as the engine's
+    slots decode), one past the ring window: each row's decode logits equal
+    its own prefill's."""
+    cfg, params, moe, caches, want, nxt, lens = _rows_setup(arch)
+    dec, _ = decode_step(params, nxt, caches, jnp.asarray(lens), cfg, moe,
+                         policy=F32)
+    np.testing.assert_allclose(np.asarray(dec), np.asarray(want), atol=2e-3)
+
+
+@pytest.mark.parametrize("outside", ["one", "every"])
+@pytest.mark.parametrize("arch", ["qwen3-4b", "h2o-danube-1.8b",
+                                  "deepseek-v2-lite-16b"])
+def test_decode_row_outside_cache_writes_nothing(arch, outside):
+    """A row whose position lies outside the cache (past a full cache's end;
+    negative for a ring, which any position >= 0 maps into) writes nothing:
+    the decode writes only the other rows' entries at their positions, and
+    with every row outside leaves the caches as they were."""
+    from repro.models.attention import TIME_AXIS
+    cfg, params, moe, caches, want, nxt, lens = _rows_setup(arch)
+    far = -1 if cfg.attn_kind == "swa" else ROW_T
+    pos = np.array(lens if outside == "one" else (far,) * 3, np.int32)
+    pos[-1] = far
+    dec, new = decode_step(params, nxt, caches, jnp.asarray(pos), cfg, moe,
+                           policy=F32)
+
+    def check(path, old, got):
+        old, got = np.array(old), np.asarray(got)
+        b_ax = int(path[0].key == "stack")
+        t_ax = b_ax + TIME_AXIS[path[-1].key]
+        for b, p in enumerate(pos[:-1] if outside == "one" else ()):
+            ix = [slice(None)] * old.ndim
+            ix[b_ax], ix[t_ax] = b, p % old.shape[t_ax]
+            assert not np.array_equal(old[tuple(ix)], got[tuple(ix)])
+            old[tuple(ix)] = got[tuple(ix)]
+        np.testing.assert_array_equal(got, old)
+
+    jax.tree_util.tree_map_with_path(check, caches, new)
+    if outside == "one":
+        np.testing.assert_allclose(np.asarray(dec[:-1]),
+                                   np.asarray(want[:-1]), atol=2e-3)
+
+
 @pytest.mark.parametrize("arch", ARCHS)
 def test_full_config_parameter_count(arch):
     """Analytic param counts of the FULL configs land near the published
