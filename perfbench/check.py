@@ -24,8 +24,22 @@ SAMPLE = 8                   # requests compared per run
 
 
 def reference(cfg: dict):
-    """The module named by the configuration's ``reference`` key."""
-    return importlib.import_module(f"perfbench.references.{cfg['reference']}")
+    """The family module the configuration names under ``reference``,
+    ``perfbench/references/<name>.py``: the one lookup through which the
+    harness reaches whatever depends on the architecture (the program's
+    config, the weights, the roofline counts and the plain reference; the
+    interface is set out in ``perfbench/references/dense.py``).  A name
+    with no such module is an error that names it."""
+    family = cfg["reference"]
+    name = f"perfbench.references.{family}"
+    try:
+        return importlib.import_module(name)
+    except ModuleNotFoundError as e:
+        if e.name != name:
+            raise
+        raise ModuleNotFoundError(
+            f"reference {family!r}: no family module "
+            f"perfbench/references/{family}.py", name=name) from None
 
 
 def sample(served: dict, seed: int, k: int = SAMPLE) -> list[int]:

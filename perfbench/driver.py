@@ -1,8 +1,9 @@
 """Drive one cell through the program's serving engine.
 
 The engine is built from ``serve()``'s own pieces (``SCHEDULERS``, the bf16
-``DtypePolicy.serve()``, the configuration's ``EngineConfig``) around
-weights from ``perfbench.weights``.  The harness owns the clock: it adds each
+``DtypePolicy.serve()``, the configuration's ``EngineConfig``) around the
+``ModelConfig`` and weights of the configuration's family module
+(``perfbench.check.reference``).  The harness owns the clock: it adds each
 request at its due time, with ``arrival_time`` set to that due time, and
 calls ``ServingEngine.tick`` in between, so time to first token counts the
 wait a long tick imposes.
@@ -19,7 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from perfbench import traffic, weights
+from perfbench import check, traffic
 from perfbench.stats import Sent
 
 HISTORY_ID = 1 << 40
@@ -53,19 +54,9 @@ class Compiles:
 
 
 def model_config(c: dict):
-    """The program's ``ModelConfig`` for a configuration file."""
-    from repro.configs.base import ModelConfig
-    window = c["sliding_window"]
-    return ModelConfig(
-        name=c["name"], family="dense", n_layers=c["num_hidden_layers"],
-        d_model=c["hidden_size"], n_heads=c["num_attention_heads"],
-        n_kv_heads=c["num_key_value_heads"], d_ff=c["intermediate_size"],
-        vocab_size=c["vocab_size"], head_dim=c["head_dim"],
-        qk_norm=c["qk_norm"], attn_kind="swa" if window else "full",
-        window=window or 0, rope_theta=float(c["rope_theta"]),
-        norm_eps=float(c["rms_norm_eps"]),
-        tie_embeddings=c["tie_word_embeddings"],
-        max_seq_len=c["max_position_embeddings"])
+    """The program's ``ModelConfig`` for a configuration file, from its
+    family module."""
+    return check.reference(c).model_config(c)
 
 
 def engine_config(c: dict):
@@ -131,6 +122,7 @@ class Cell:
     def __init__(self, cfg: dict, mix: dict, params: dict, seed: int,
                  scheduler: Optional[str] = None):
         self.cfg, self.mix, self.params, self.seed = cfg, mix, params, seed
+        self.family = check.reference(cfg)
         self.scheduler = scheduler or params.get("scheduler", "ewsjf")
         self.rate = float(params["rate_rps"])
         self.eng = None
@@ -143,10 +135,10 @@ class Cell:
         from repro.serving.api import SCHEDULERS
         from repro.serving.engine import ServingEngine
         Compiles.listen()
-        w = weights.program_params(self.seed, self.cfg, dtype)
+        w = self.family.program_params(self.seed, self.cfg, dtype)
         policy = (DtypePolicy.serve() if dtype == jnp.bfloat16 else
                   DtypePolicy(dtype, dtype, jnp.float32))
-        self.eng = ServingEngine(model_config(self.cfg), w,
+        self.eng = ServingEngine(self.family.model_config(self.cfg), w,
                                  SCHEDULERS[self.scheduler](),
                                  engine_config(self.cfg), policy=policy)
         jax.block_until_ready(self.eng.params)

@@ -5,10 +5,12 @@
         --seconds 51 --trace 0
 
 The cell is an entry of ``BENCHMARK.json``'s ``workloads``; its files are
-found by name: ``perfbench/configs/<config>.json`` (sizes, engine, and the
-reference module), ``perfbench/traffic/<traffic>.json`` (the mix) and
-``perfbench/cells/<cell>.json`` (rate, scheduler, check limits).  Metric
-``m`` is read by ``perfbench/metrics/<m>.py``.
+found by name: ``perfbench/configs/<config>.json`` (sizes, engine, and under
+``reference`` the family module ``perfbench/references/<reference>.py``,
+which supplies the program's ``ModelConfig``, the weights, the roofline
+counts and the plain reference), ``perfbench/traffic/<traffic>.json`` (the
+mix) and ``perfbench/cells/<cell>.json`` (rate, scheduler, check limits).
+Metric ``m`` is read by ``perfbench/metrics/<m>.py``.
 
 ``--trace 0`` prints the cell's end-to-end metrics; ``--trace 1`` records a
 profiler trace of the window and prints the per-layer metrics.  The

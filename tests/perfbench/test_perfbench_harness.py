@@ -2,9 +2,11 @@
 
 The generator, the exact statistics, the trace reduction (on a trace
 recorded on the chip), the operation and byte counts, the weights' layout,
-and the plain reference against the engine at a tiny size.  The last tests
-drive a whole run past the harness's look for a chip, once sound and once
-with the timed path broken underneath, and see ``correct`` follow.
+and the plain reference against the engine at a tiny size.  Then whole runs
+driven past the harness's look for a chip, once sound and once with the
+timed path broken underneath, to see ``correct`` follow.  Last, the family
+lookup: the dense family's numbers pinned to those it gave before it moved
+into its module, and a family that is one module and nothing else.
 """
 
 from __future__ import annotations
@@ -21,6 +23,7 @@ import pytest
 from perfbench import check, driver, roofline, stats, tracefile, traffic, weights
 from perfbench import run as bench
 from perfbench.rundata import METRICS, RunData, reader
+from perfbench.references import dense
 
 ROOT = Path(__file__).resolve().parents[2]
 HERE = Path(__file__).resolve().parent
@@ -286,7 +289,7 @@ def test_weights_follow_the_seed_and_the_reference_draws_them_alike():
     c = weights.program_params(3, TINY)
     assert np.array_equal(a["embed"], b["embed"])
     assert not np.array_equal(a["embed"], c["embed"])
-    one = weights.layer(weights.root_key(2 ** 32 + 3), TINY, 1, jnp.bfloat16)
+    one = dense.layer(weights.root_key(2 ** 32 + 3), TINY, 1, jnp.bfloat16)
     assert np.array_equal(one["wq"],
                           a["blocks"]["stack"]["slot_0"]["mixer"]["wq"][1])
 
@@ -387,3 +390,200 @@ def test_no_chip_means_no_result(capsys):
     with pytest.raises(SystemExit) as e:
         bench.require_chips(1)
     assert e.value.code == 3
+
+
+# ---- families: the lookup, and the dense family pinned --------------------
+
+# Read before the dense family's code moved out of driver.py, weights.py and
+# roofline.py into perfbench/references/dense.py; the move changed no
+# arithmetic, so every number stays to the bit.
+POSITIONS = {"[0]": [0], "[99]": [99], "[0, 511, 1023]": [0, 511, 1023]}
+LENS = {"[16]": [16], "[100, 512]": [100, 512]}
+PINNED_COUNTS = {
+    "qwen3-4b": {
+        "weight_bytes": 8044544000, "kv_bytes_per_token": 147456,
+        "param_count": 4022468096,
+        "decode_flops [0]": 8045133824, "decode_bytes [0]": 8044691456,
+        "decode_flops [99]": 8103526400, "decode_bytes [99]": 8059289600,
+        "decode_flops [0, 511, 1023]": 25040191488,
+        "decode_bytes [0, 511, 1023]": 8271183872,
+        "prefill_flops [16]": 117124235264,
+        "prefill_bytes [16]": 8046903296,
+        "prefill_flops [100, 512]": 4529173430272,
+        "prefill_bytes [100, 512]": 8134787072},
+    "h2o-danube-1.8b": {
+        "weight_bytes": 3498311680, "kv_bytes_per_token": 61440,
+        "param_count": 1831201280,
+        "decode_flops [0]": 3498557440, "decode_bytes [0]": 3498373120,
+        "decode_flops [99]": 3522887680, "decode_bytes [99]": 3504455680,
+        "decode_flops [0, 511, 1023]": 10872668160,
+        "decode_bytes [0, 511, 1023]": 3592744960,
+        "prefill_flops [16]": 53548810240,
+        "prefill_bytes [16]": 3499294720,
+        "prefill_flops [100, 512]": 2074540605440,
+        "prefill_bytes [100, 512]": 3535912960},
+}
+PINNED_MODEL_CONFIG = {
+    "qwen3-4b": {
+        'name': 'qwen3-4b', 'family': 'dense', 'n_layers': 36, 'd_model': 2560,
+        'n_heads': 32, 'n_kv_heads': 8, 'd_ff': 9728, 'vocab_size': 151936,
+        'head_dim': 128, 'attn_kind': 'full', 'window': 0,
+        'local_global_period': 0, 'qk_norm': True, 'rope_theta': 1000000.0,
+        'causal': True, 'use_mla': False, 'kv_lora_rank': 0,
+        'rope_head_dim': 64, 'v_head_dim': 128, 'n_experts': 0,
+        'n_shared_experts': 0, 'moe_top_k': 2, 'moe_d_ff': 0,
+        'capacity_factor': 1.25, 'first_dense_layers': 0, 'ssm_state': 0,
+        'ssm_head_dim': 64, 'd_inner': 0, 'ssm_chunk': 256, 'conv_width': 4,
+        'ssm_groups': 1, 'pattern': ('attn',), 'rnn_width': 2560,
+        'input_mode': 'tokens', 'is_encoder_only': False,
+        'tie_embeddings': True, 'subquadratic': False, 'max_seq_len': 1024,
+        'norm_eps': 1e-06, 'logit_softcap': 0.0},
+    "h2o-danube-1.8b": {
+        'name': 'h2o-danube-1.8b', 'family': 'dense', 'n_layers': 24,
+        'd_model': 2560, 'n_heads': 32, 'n_kv_heads': 8, 'd_ff': 6912,
+        'vocab_size': 32000, 'head_dim': 80, 'attn_kind': 'swa',
+        'window': 4096, 'local_global_period': 0, 'qk_norm': False,
+        'rope_theta': 10000.0, 'causal': True, 'use_mla': False,
+        'kv_lora_rank': 0, 'rope_head_dim': 64, 'v_head_dim': 80,
+        'n_experts': 0, 'n_shared_experts': 0, 'moe_top_k': 2, 'moe_d_ff': 0,
+        'capacity_factor': 1.25, 'first_dense_layers': 0, 'ssm_state': 0,
+        'ssm_head_dim': 64, 'd_inner': 0, 'ssm_chunk': 256, 'conv_width': 4,
+        'ssm_groups': 1, 'pattern': ('attn',), 'rnn_width': 2560,
+        'input_mode': 'tokens', 'is_encoder_only': False,
+        'tie_embeddings': False, 'subquadratic': False, 'max_seq_len': 1024,
+        'norm_eps': 1e-05, 'logit_softcap': 0.0},
+}
+PINNED_TINY_DIGEST = {
+    False: "218fbcff58ac760d26d5d977eead4cea038a12443340a0b2c638efa5ca20fbd4",
+    True: "a5077d924b8a6952f3f04eb7398eea35b7b3b3c93cc394bda608a4c01df317b6"}
+PINNED_READERS = {"kernel.decode_roofline": 62.334815852737776,
+                  "kernel.prefill_roofline": 82.28281231559622,
+                  "step.mfu": 6.578337786434969}
+ROOFLINE_READERS = sorted(PINNED_READERS)
+
+
+def counts(cfg: dict) -> dict:
+    """The six counts and the parameters, keyed as in PINNED_COUNTS."""
+    out = {"weight_bytes": roofline.weight_bytes(cfg),
+           "kv_bytes_per_token": roofline.kv_bytes_per_token(cfg),
+           "param_count": weights.param_count(cfg)}
+    for k, p in POSITIONS.items():
+        out[f"decode_flops {k}"] = roofline.decode_flops(cfg, p)
+        out[f"decode_bytes {k}"] = roofline.decode_bytes(cfg, p)
+    for k, n in LENS.items():
+        out[f"prefill_flops {k}"] = roofline.prefill_flops(cfg, n)
+        out[f"prefill_bytes {k}"] = roofline.prefill_bytes(cfg, n)
+    return out
+
+
+@pytest.mark.parametrize("name,count", [
+    (n, k) for n in PINNED_COUNTS for k in PINNED_COUNTS[n]])
+def test_dense_counts_are_pinned(name, count):
+    got = counts(config(name))[count]
+    assert type(got) is int and got == PINNED_COUNTS[name][count]
+
+
+@pytest.mark.parametrize("name", sorted(PINNED_MODEL_CONFIG))
+def test_dense_model_config_is_pinned(name):
+    import dataclasses
+    assert dataclasses.asdict(driver.model_config(config(name))) == \
+        PINNED_MODEL_CONFIG[name]
+
+
+def _digest(tree) -> str:
+    import hashlib
+    h = hashlib.sha256()
+    for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
+        a = np.asarray(leaf)
+        h.update(f"{jax.tree_util.keystr(path)} {a.dtype} {a.shape}".encode())
+        h.update(a.tobytes())
+    return h.hexdigest()
+
+
+@pytest.mark.parametrize("tied", [False, True], ids=["untied", "tied"])
+def test_tiny_dense_weights_are_pinned(tied):
+    cfg = dict(TINY, tie_word_embeddings=tied)
+    assert _digest(weights.program_params(2 ** 32 + 3, cfg)) == \
+        PINNED_TINY_DIGEST[tied]
+
+
+def recorded_run(cfg: dict, trace) -> RunData:
+    """The recorded chip trace (device programs only) under a fixed,
+    made-up record of the window: 12 ticks of 4 decode steps over 8 slots
+    spread through the cache, and 4 prefill batches."""
+    ticks = [(0.05 + 0.075 * k, [(96 * j + 4 * k, 4) for j in range(8)])
+             for k in range(12)]
+    batches = [(0.1, [16]), (0.3, [100, 512]), (0.6, [48, 48, 48]),
+               (0.9, [300])]
+    w = driver.Window(open=0.0, close=1.0, ticks=ticks, batches=batches,
+                      traced=(0.0, 1.0))
+    return RunData(cfg, w, 1.0, 0, "TPU v5 lite", trace)
+
+
+@pytest.mark.parametrize("metric", ROOFLINE_READERS)
+def test_roofline_readers_are_pinned_on_the_recorded_trace(metric,
+                                                           chip_trace):
+    run = recorded_run(config("qwen3-4b"), chip_trace)
+    assert reader(metric)(run) == PINNED_READERS[metric]
+
+
+def _twin_family(seen: list):
+    """A family module made in the test: tiny-dense's program and reference
+    at another draw of the weights (the seed plus one, on both sides), and
+    twice dense's counts."""
+    import dataclasses
+    import types
+    mod = types.ModuleType("perfbench.references.twin")
+
+    def note(name, fn):
+        def call(*a, **kw):
+            seen.append(name)
+            return fn(*a, **kw)
+        setattr(mod, name, call)
+
+    note("model_config", lambda c: dataclasses.replace(
+        dense.model_config(c), name="twin"))
+    note("program_params", lambda seed, c, dtype=jnp.bfloat16:
+         dense.program_params(seed + 1, c, dtype))
+    note("hidden", lambda seed, c, tokens, mode="f32":
+         dense.hidden(seed + 1, c, tokens, mode))
+    note("readout", lambda seed, c, h, targets, mode="f32":
+         dense.readout(seed + 1, c, h, targets, mode))
+    for name in ("param_count", "weight_bytes", "kv_bytes_per_token",
+                 "decode_flops", "decode_bytes", "prefill_flops",
+                 "prefill_bytes"):
+        note(name, lambda *a, f=getattr(dense, name): 2 * f(*a))
+    return mod
+
+
+def test_a_family_is_its_module_alone(monkeypatch, chip_trace):
+    """A module found only by the lookup drives the engine's build, the
+    check and the roofline readers: no other harness file knows it."""
+    import sys
+    seen = []
+    monkeypatch.setitem(sys.modules, "perfbench.references.twin",
+                        _twin_family(seen))
+    res = tiny_run(monkeypatch, cfg=dict(TINY, reference="twin"))
+    # Sound only if the engine and the reference drew the same (twin's)
+    # weights: dense's own draw at this seed is another model.
+    assert res["correct"] and res["checks"]["logit_gap"]["value"] < 0.1
+    assert seen[:2] == ["model_config", "program_params"] or \
+        seen[:2] == ["program_params", "model_config"]
+    assert {"hidden", "readout"} <= set(seen[2:])
+    for name in ("qwen3-4b", "h2o-danube-1.8b"):
+        twin = dict(config(name), reference="twin")
+        assert counts(twin) == {k: 2 * v for k, v in
+                                PINNED_COUNTS[name].items()}
+    run = recorded_run(dict(config("qwen3-4b"), reference="twin"),
+                       chip_trace)
+    for m in ROOFLINE_READERS:
+        assert reader(m)(run) == 2 * PINNED_READERS[m], m
+
+
+def test_an_unknown_family_fails_at_once(monkeypatch):
+    built = []
+    monkeypatch.setattr(driver.Cell, "build",
+                        lambda self, *a: built.append(self))
+    with pytest.raises(ModuleNotFoundError, match="no_such_family"):
+        tiny_run(monkeypatch, cfg=dict(TINY, reference="no_such_family"))
+    assert built == []
